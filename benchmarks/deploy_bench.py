@@ -35,6 +35,7 @@ from repro.deploy import emit_c, verify
 from repro.deploy.goldens import build_reference_model
 from repro.deploy.image import size_report, audit_platforms
 from repro.deploy.qvm import QVM
+from repro.kernels import enable_compile_cache
 
 
 def bench_qvm(vm: QVM, xq: np.ndarray, repeats: int = 3) -> dict:
@@ -95,6 +96,7 @@ def main() -> None:
                          "counters/gauges plus the monitored qvm's "
                          "numeric-health series over the same windows")
     args = ap.parse_args()
+    enable_compile_cache()
 
     if args.trained:
         params, calib = verify.protocol_model()

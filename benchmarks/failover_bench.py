@@ -36,6 +36,7 @@ from repro.core.quantization import quantize_params, QuantConfig
 from repro.data import hapt
 from repro.serve.fleet import FleetConfig, FleetEngine
 from repro.serve.streaming import StreamingConfig
+from repro.kernels import enable_compile_cache
 
 
 def _build(qp, args, obs=None) -> FleetEngine:
@@ -109,6 +110,7 @@ def main() -> None:
                              "fleet's registry (tick/crash series plus "
                              "numeric-health counters) across all reps")
     args = parser.parse_args()
+    enable_compile_cache()
     if args.smoke:
         args.slots_per_shard, args.samples = 256, 64
         args.ticks_before, args.reps = 10, 2

@@ -37,6 +37,7 @@ import numpy as np
 from repro.core import fastgrnn as fg
 from repro.core.quantization import quantize_params, QuantConfig
 from repro.data import hapt
+from repro.kernels import enable_compile_cache
 from repro.kernels.fastgrnn_cell.ops import Q15StreamStep
 from repro.obs import MetricsRegistry, Observability, TRANSFER_KEYS
 from repro.serve.fleet import FleetConfig, FleetEngine
@@ -147,6 +148,7 @@ def main() -> None:
     parser.add_argument("--smoke", action="store_true",
                         help="CI configuration: tiny fleet, 1 window")
     args = parser.parse_args()
+    enable_compile_cache()
     if args.smoke:
         args.shards, args.slots_per_shard = "1,2", 256
         args.capacity_shards, args.capacity_slots = 4, 256
@@ -225,12 +227,12 @@ def main() -> None:
           f"{capacity['realtime_streams_50hz']:,} real-time 50 Hz sensors "
           f"(sustained: {capacity['sustained_realtime_50hz']})", flush=True)
 
-    # achieved-vs-peak at the capacity point's measured aggregate rate,
-    # against the launch/roofline.py hardware model (satellite of the
-    # MXU-shaped kernel layout — reports both the real cell's FLOPs and
-    # what the 128-lane padded layout actually issues)
-    kern = Q15StreamStep(qp, backend=args.backend,
-                         mxu=(args.backend == "pallas"))
+    # work per stream-step from the cell's shapes; achieved-vs-peak only
+    # where the step ran on a device with published peaks (a CPU rate is
+    # not a device metric, so a CPU run records the counts alone)
+    kern = Q15StreamStep(qp, backend=args.backend)
+    rate = capacity["stream_steps_per_sec"]
+    on_chip = args.backend != "exact" and jax.default_backend() == "tpu"
     record = {
         "benchmark": "fleet_sharding",
         "model": "FastGRNN H=16 r_w=2 r_u=8, Q15 PTQ (566-byte class)",
@@ -252,7 +254,8 @@ def main() -> None:
             p: max(r["scaling_x"] for r in rows if r["placement"] == p)
             for p in resolved},
         "capacity": capacity,
-        "kernel_roofline": kern.roofline(capacity["stream_steps_per_sec"]),
+        "kernel_roofline": (kern.roofline(rate) if on_chip
+                            else kern.work_per_stream_step()),
     }
     with open(args.out, "w") as f:
         json.dump(record, f, indent=2)

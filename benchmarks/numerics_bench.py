@@ -43,6 +43,7 @@ from repro.deploy.image import build_image
 from repro.deploy.qvm import QVM
 from repro.obs import MetricsRegistry, Observability
 from repro.obs.numerics import NumericsMonitor, site_order
+from repro.kernels import enable_compile_cache
 
 #: Input gain that drives the reference model's ``h_next`` site into
 #: saturation (the stress witness both engines must agree on).
@@ -207,6 +208,7 @@ def main() -> None:
                     help="streams in the engine-overhead drain")
     ap.add_argument("--reps", type=int, default=3)
     args = ap.parse_args()
+    enable_compile_cache()
 
     art = build_reference_artifact(seed=0)
     img = build_image(art)

@@ -41,6 +41,7 @@ from repro.data import hapt
 from repro.obs import Observability
 from repro.serve.fleet import FleetConfig, FleetEngine, crash_matrix
 from repro.serve.streaming import StreamingConfig
+from repro.kernels import enable_compile_cache
 
 
 def _build(qp, shards: int, slots: int, windows: int, obs, *,
@@ -135,6 +136,7 @@ def main() -> None:
     parser.add_argument("--smoke", action="store_true",
                         help="CI configuration: tiny fleet, 1 window")
     args = parser.parse_args()
+    enable_compile_cache()
     if args.smoke:
         args.shards, args.slots_per_shard, args.windows = 2, 256, 1
         args.reps = 1

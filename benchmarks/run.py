@@ -10,6 +10,8 @@ import traceback
 
 
 def main() -> None:
+    from repro.kernels import enable_compile_cache
+    enable_compile_cache()
     from . import tables_accuracy as acc
     from . import tables_deploy as dep
     from . import roofline_table as roof

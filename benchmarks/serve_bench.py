@@ -33,6 +33,7 @@ import numpy as np
 import repro.configs as C
 from repro.models import transformer as T
 from repro.serve.engine import Engine, ServeConfig
+from repro.kernels import enable_compile_cache
 
 
 def mixed_budgets(rng, n, lo, hi, long_lo, long_hi, long_frac=0.25):
@@ -96,6 +97,7 @@ def main() -> None:
                     help="tiny config for CI schema validation")
     ap.add_argument("--out", default="BENCH_serve.json")
     args = ap.parse_args()
+    enable_compile_cache()
 
     full = C.get(args.arch)
     if not full.has_decode:

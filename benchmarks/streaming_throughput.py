@@ -41,6 +41,7 @@ from repro.data import hapt
 from repro.obs import MetricsRegistry, Observability
 from repro.serve.fleet import FleetConfig, FleetEngine
 from repro.serve.streaming import StreamingEngine, StreamingConfig
+from repro.kernels import enable_compile_cache
 
 FULL = os.environ.get("REPRO_FULL", "0") == "1"
 CONCURRENCY = (256, 1024, 2048, 4096) if FULL else (256, 1024, 2048)
@@ -123,6 +124,7 @@ def main() -> None:
                              "attached and write its snapshot (schema "
                              "'metrics_snapshot') to this path")
     args = parser.parse_args()
+    enable_compile_cache()
     concurrency = (tuple(int(c) for c in args.concurrency.split(","))
                    if args.concurrency else CONCURRENCY)
     # metrics-only bundle: counters/gauges/histograms accumulate across

@@ -55,11 +55,9 @@ SCHEMAS: dict[str, dict] = {
                      "transfers", "zero_copy_h"],
         # device-residency gate: h-state bytes over the steady window
         # (repro.obs.transfers.TRANSFER_KEYS, per-row under "transfers")
+        # (achieved-vs-peak keys only in a run on a chip with peaks)
         "kernel_roofline": ["backend", "model_flops_per_stream_step",
-                            "padded_flops_per_stream_step",
-                            "hbm_bytes_per_stream_step", "achieved_gflops",
-                            "peak_fraction",
-                            "memory_bound_stream_steps_per_sec"],
+                            "hbm_bytes_per_stream_step"],
     },
     # benchmarks/failover_bench.py: crash/recovery latency for a shard
     # holding `slots_per_shard` streams.  `recovery` pins the headline

@@ -33,6 +33,7 @@ from repro.data import hapt
 from repro.deploy import emit_c, verify
 from repro.deploy.goldens import build_reference_artifact
 from repro.deploy.image import audit_platforms, build_image, size_report
+from repro.kernels import enable_compile_cache
 
 
 def main() -> None:
@@ -45,6 +46,7 @@ def main() -> None:
     ap.add_argument("--bits", type=int, default=15, choices=(15, 7),
                     help="weight format: 15 = Q15/int16 (paper), 7 = Q7/int8")
     args = ap.parse_args()
+    enable_compile_cache()
 
     # 1+2: model -> compression pipeline -> ONE artifact (the same
     # reference recipe the golden fixtures pin, so the Q15 default is

@@ -17,12 +17,14 @@ from repro.core import fastgrnn as fg, pipeline as pl, compression as comp
 from repro.core import mcu, energy as en, warmup
 from repro.data import hapt
 from repro.configs import fastgrnn_har as paper
+from repro.kernels import enable_compile_cache
 
 parser = argparse.ArgumentParser()
 parser.add_argument("--fast", action="store_true",
                     help="reduced data/epochs (CI-sized)")
 parser.add_argument("--seed", type=int, default=0)
 args = parser.parse_args()
+enable_compile_cache()
 
 n_train = 2500 if args.fast else None
 epochs = 50 if args.fast else paper.EPOCHS

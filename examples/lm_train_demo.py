@@ -19,6 +19,7 @@ from repro.data import tokens
 from repro.models import registry
 from repro.train.optimizer import AdamConfig
 from repro.train.trainer import Trainer, TrainerConfig
+from repro.kernels import enable_compile_cache
 
 parser = argparse.ArgumentParser()
 parser.add_argument("--arch", default="qwen2-1.5b", choices=list(C.ARCHS))
@@ -27,6 +28,7 @@ parser.add_argument("--batch", type=int, default=8)
 parser.add_argument("--seq", type=int, default=64)
 parser.add_argument("--ckpt-dir", default="/tmp/repro_lm_demo")
 args = parser.parse_args()
+enable_compile_cache()
 
 cfg = C.reduced(C.get(args.arch), d_model=128, num_layers=4,
                 num_heads=4 if C.get(args.arch).num_heads else 0)

@@ -10,6 +10,9 @@ import numpy as np
 
 from repro.core import fastgrnn as fg, pipeline as pl, compression as comp
 from repro.data import hapt
+from repro.kernels import enable_compile_cache
+
+enable_compile_cache()
 
 # 1. data (synthetic HAPT: 128-sample tri-axial windows @ 50 Hz, 6 classes)
 train = hapt.load("train", n=2000)

@@ -26,6 +26,7 @@ import repro.configs as C
 from repro.compress import tree_size_report
 from repro.models import registry
 from repro.serve.engine import Engine, ServeConfig
+from repro.kernels import enable_compile_cache
 
 parser = argparse.ArgumentParser()
 parser.add_argument("--arch", default="deepseek-7b", choices=list(C.ARCHS))
@@ -39,6 +40,7 @@ parser.add_argument("--metrics-out", default=None,
                          "metrics) and write the metrics snapshot JSON "
                          "(schema 'metrics_snapshot') to this path")
 args = parser.parse_args()
+enable_compile_cache()
 
 
 def _make_obs():

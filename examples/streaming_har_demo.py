@@ -19,12 +19,14 @@ from repro.core import fastgrnn as fg, pipeline as pl
 from repro.core.qruntime import QRuntime
 from repro.data import hapt
 from repro.serve.streaming import StreamingEngine, StreamingConfig
+from repro.kernels import enable_compile_cache
 
 parser = argparse.ArgumentParser()
 parser.add_argument("--streams", type=int, default=12)
 parser.add_argument("--slots", type=int, default=4)
 parser.add_argument("--epochs", type=int, default=30)
 args = parser.parse_args()
+enable_compile_cache()
 
 # 1. train + deploy (paper config: H=16, r_w=2, r_u=8, Q15 PTQ)
 train = hapt.load("train", n=1500)

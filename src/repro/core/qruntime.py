@@ -13,7 +13,7 @@ Mirrors the deployed ~200-line fastgrnn.cpp translation unit:
 
 Three execution paths are provided, matching the paper's verification
 protocol: (1) FP32 reference (core/fastgrnn.py), (2) this NumPy
-C-equivalent, (3) the Pallas fastgrnn_cell kernel (interpret mode).  The
+C-equivalent, (3) the Pallas fastgrnn_cell kernel.  The
 cross-platform agreement benchmark compares argmax predictions of all
 three over the full test set.
 """

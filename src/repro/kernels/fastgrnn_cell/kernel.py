@@ -1,18 +1,13 @@
-"""Pallas TPU kernel: fused FastGRNN full-window scan (paper Eq. 1-3 +
-Sec. III-E LUT activations).
+"""Pallas TPU kernels: fused FastGRNN full-window scan and batched single
+step (paper Eq. 1-3 + Sec. III-E LUT activations).
 
 MCU -> TPU adaptation (DESIGN.md Sec. 2): on the MSP430 the weights live in
-Flash and the ~300 B working set in SRAM for the whole 128-sample window.
-Here the low-rank factors, biases, both LUTs AND the hidden state stay
-resident in VMEM for the entire window — one HBM read of x, one write of
-the trajectory, zero weight re-fetches, and the per-step dispatch overhead
-of 128 separate cell calls collapses into one kernel launch (the TPU
-analogue of the paper's 30.5x LUT win being about *eliminating per-step
-overhead*, not raw FLOPs).
-
-Grid: one program per batch tile; fori_loop over T inside the kernel.
-Dims are padded to the (8,128) float32 tile by ops.py; the real H=16,d=3
-cell uses a (B_tile, 128)-padded layout where lanes beyond H/d are zero.
+Flash and the ~300 B working set in SRAM; here weights, biases, both LUTs
+and the hidden state stay resident in VMEM for the whole kernel.  Both
+kernels use the (rows, 128) float32 lane layout: the real H=16, d=3 cell
+is padded to 128 lanes, and lanes beyond H/d are zero and inert.  A LUT
+is held as a (2, 128) block and read with two lane gathers
+(:func:`lut_lookup`); Mosaic lowers no other gather form.
 """
 from __future__ import annotations
 
@@ -23,83 +18,82 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 
+from repro.kernels import interpret_mode
 from . import qstep
 
 B_TILE = 8
+LANES = 128
 
 
-def _cell_kernel(sig_lut_ref, tanh_lut_ref, x_ref, w_ref, u_ref,
-                 bz_ref, bh_ref, scal_ref, h_ref, traj_ref,
-                 *, T: int, lo: float, hi: float):
-    """x: (T, B_TILE, Dp); w: (Dp, Hp) = W^T (pre-multiplied low-rank);
-    u: (Hp, Hp) = U^T; scal: (2,) [zeta, nu] post-sigmoid; outputs:
-    h (B_TILE, Hp), traj (T, B_TILE, Hp)."""
-    size = sig_lut_ref.shape[0]
-    bw = (hi - lo) / size
-    inv_bw = 1.0 / bw
+def lut_block(table) -> jax.Array:
+    """A 256-entry LUT as the (2, 128) block :func:`lut_lookup` reads."""
+    return jnp.asarray(np.asarray(table, np.float32).reshape(2, LANES))
 
-    def lut(table, v):
-        idx = jnp.clip(((v - lo) * inv_bw).astype(jnp.int32), 0, size - 1)
-        y = jnp.take(table, idx)
-        return jnp.where(v >= hi, table[size - 1],
-                         jnp.where(v <= lo, table[0], y))
 
-    w = w_ref[...]
-    u = u_ref[...]
-    b_z = bz_ref[...]
-    b_h = bh_ref[...]
-    zeta = scal_ref[0]
-    nu = scal_ref[1]
-    sig_t = sig_lut_ref[...]
-    tanh_t = tanh_lut_ref[...]
+def lut_lookup(table, v):
+    """Nearest-bucket LUT over a (rows, 128) block; ``table`` is the
+    (2, 128) value of :func:`lut_block`.  Bit-identical to
+    ``qstep.lut_eval_batched``: the same clipped bucket index, moved to
+    the edge buckets where the reference overrides the value, then read
+    as one lane gather per 128-entry half and a select."""
+    idx = jnp.clip(((v - qstep.INPUT_MIN) * qstep.INV_BW).astype(jnp.int32),
+                   0, qstep.LUT_SIZE - 1)
+    idx = jnp.where(v <= qstep.INPUT_MIN, 0,
+                    jnp.where(v >= qstep.INPUT_MAX, qstep.LUT_SIZE - 1, idx))
+    lane = idx & (LANES - 1)
+    row = lambda r: jnp.broadcast_to(table[r:r + 1], v.shape)
+    lo, hi = (jnp.take_along_axis(row(r), lane, axis=1) for r in (0, 1))
+    return jnp.where(idx >= LANES, hi, lo)
+
+
+def _cell_kernel(sig_ref, tanh_ref, x_ref, w_ref, u_ref, b_ref, h_ref,
+                 traj_ref, *, T: int):
+    """x: (T, B_TILE, Dp); w: (Dp, Hp) = W^T; u: (Hp, Hp) = U^T; b: (4, Hp)
+    rows [b_z, b_h, zeta, nu] (the post-sigmoid scalars broadcast over
+    lanes); outputs: h (B_TILE, Hp), traj (T, B_TILE, Hp)."""
+    w, u = w_ref[...], u_ref[...]
+    b_z, b_h, zeta, nu = (b_ref[i:i + 1, :] for i in range(4))
+    sig_t, tanh_t = sig_ref[...], tanh_ref[...]
 
     def step(t, h):
-        x_t = x_ref[t]                                   # (B_TILE, Dp)
-        pre = jnp.dot(x_t, w, preferred_element_type=jnp.float32) \
+        pre = jnp.dot(x_ref[t], w, preferred_element_type=jnp.float32) \
             + jnp.dot(h, u, preferred_element_type=jnp.float32)
-        z = lut(sig_t, pre + b_z)
-        h_tilde = lut(tanh_t, pre + b_h)
+        z = lut_lookup(sig_t, pre + b_z)
+        h_tilde = lut_lookup(tanh_t, pre + b_h)
         h_new = (zeta * (1.0 - z) + nu) * h_tilde + z * h
         traj_ref[t] = h_new
         return h_new
 
-    h = jnp.zeros_like(h_ref)
-    h = jax.lax.fori_loop(0, T, step, h)
-    h_ref[...] = h
+    h_ref[...] = jax.lax.fori_loop(0, T, step,
+                                   jnp.zeros(h_ref.shape, jnp.float32))
 
 
-@functools.partial(jax.jit, static_argnames=("T", "lo", "hi", "interpret"))  # detlint: ignore[det-jit-pallas] fixed window shapes (ops.py pads pre-call); resident path builds its own eager-pad wrapper
-def fastgrnn_window(sig_lut, tanh_lut, x, w_t, u_t, b_z, b_h, scal,
-                    *, T: int, lo: float = -8.0, hi: float = 8.0,
-                    interpret: bool = True):
-    """x: (T, B, Dp); w_t: (Dp, Hp); u_t: (Hp, Hp); b_z/b_h: (Hp,);
-    scal: (2,).  B % B_TILE == 0 (ops.py pads).  Returns (h, traj)."""
+@jax.jit  # detlint: ignore[det-jit-pallas] fixed window shapes (ops.py pads pre-call); resident path builds its own eager-pad wrapper
+def fastgrnn_window(sig_lut, tanh_lut, x, w_t, u_t, b):
+    """sig_lut/tanh_lut: (2, 128) LUT blocks; x: (T, B, Dp); w_t: (Dp, Hp);
+    u_t: (Hp, Hp); b: (4, Hp) rows [b_z, b_h, zeta, nu].  B % B_TILE == 0
+    (ops.py pads).  Returns (h, traj)."""
     Tn, B, Dp = x.shape
     Hp = w_t.shape[1]
-    grid = (B // B_TILE,)
+    full = lambda shape: pl.BlockSpec(shape, lambda i: (0,) * len(shape))
     return pl.pallas_call(
-        functools.partial(_cell_kernel, T=T, lo=lo, hi=hi),
-        grid=grid,
+        functools.partial(_cell_kernel, T=Tn),
+        grid=(B // B_TILE,),
         in_specs=[
-            pl.BlockSpec((sig_lut.shape[0],), lambda b: (0,)),
-            pl.BlockSpec((tanh_lut.shape[0],), lambda b: (0,)),
-            pl.BlockSpec((Tn, B_TILE, Dp), lambda b: (0, b, 0)),
-            pl.BlockSpec((Dp, Hp), lambda b: (0, 0)),
-            pl.BlockSpec((Hp, Hp), lambda b: (0, 0)),
-            pl.BlockSpec((Hp,), lambda b: (0,)),
-            pl.BlockSpec((Hp,), lambda b: (0,)),
-            pl.BlockSpec((2,), lambda b: (0,)),
+            full(sig_lut.shape), full(tanh_lut.shape),
+            pl.BlockSpec((Tn, B_TILE, Dp), lambda i: (0, i, 0)),
+            full((Dp, Hp)), full((Hp, Hp)), full(b.shape),
         ],
         out_specs=[
-            pl.BlockSpec((B_TILE, Hp), lambda b: (b, 0)),
-            pl.BlockSpec((Tn, B_TILE, Hp), lambda b: (0, b, 0)),
+            pl.BlockSpec((B_TILE, Hp), lambda i: (i, 0)),
+            pl.BlockSpec((Tn, B_TILE, Hp), lambda i: (0, i, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((B, Hp), jnp.float32),
             jax.ShapeDtypeStruct((Tn, B, Hp), jnp.float32),
         ],
-        interpret=interpret,
-    )(sig_lut, tanh_lut, x, w_t, u_t, b_z, b_h, scal)
+        interpret=interpret_mode(),
+    )(sig_lut, tanh_lut, x, w_t, u_t, b)
 
 
 # ---------------------------------------------------------------------------
@@ -107,142 +101,119 @@ def fastgrnn_window(sig_lut, tanh_lut, x, w_t, u_t, b_z, b_h, scal,
 # ---------------------------------------------------------------------------
 # One FastGRNN step for a whole batch of independent streams: the serving
 # analogue of a fleet of deployed sensors, each slot carrying its own hidden
-# state.  Unlike the full-window scan above, weights arrive as *raw int16
-# Q15* and are dequantized on use inside the kernel (w = f32(Wq) * scale) —
-# the paper's Appendix-B recipe executed in VMEM, so HBM traffic for the
-# weight stream is halved vs f32 storage.  The body reuses the generic
-# qstep math (fixed ascending-j matvec, nearest-bucket LUT), sliced to the
-# real dims so the op order per stream matches core/qruntime.py exactly;
-# padded lanes never enter the accumulation chain.
+# state.  Weights arrive as *raw int16 Q15* and are dequantized on use
+# inside the kernel (w = f32(Wq) * scale) — the paper's Appendix-B recipe
+# executed in VMEM.  The body is qstep.step_batched in the 128-lane layout:
+# each matvec is the same ascending-j chain of one multiply and one add per
+# real inner index (padded indices never enter it), the LUT is the same
+# nearest bucket, and the Q15 activation stores are the same round/clip.
 
 
-def _q15_step_kernel(sig_ref, tanh_ref, x_ref, h_ref, mask_ref,
-                     *refs, sw: "qstep.StepWeights", d: int, H: int):
-    """x: (B_TILE, Dp); h: (B_TILE, Hp); mask: (B_TILE,) int32;
-    refs: int16 weight refs (W|W1,W2,U|U1,U2) then b_z, b_h, out."""
-    names = qstep.LOW_RANK_NAMES if sw.low_rank else qstep.FULL_RANK_NAMES
-    w_refs, (bz_ref, bh_ref, out_ref) = refs[:len(names)], refs[len(names):]
-    real = {"W": (H, d), "U": (H, H),
-            "W1": sw.w.get("W1", np.zeros((0, 0))).shape,
-            "W2": sw.w.get("W2", np.zeros((0, 0))).shape,
-            "U1": sw.w.get("U1", np.zeros((0, 0))).shape,
-            "U2": sw.w.get("U2", np.zeros((0, 0))).shape}
-    arrs = {}
-    for n, ref in zip(names, w_refs):
-        r, c = real[n]
-        # dequantize-on-use (Appendix B), sliced to real dims so the
-        # qstep matvec loops never touch a padded column
-        arrs[n] = ref[...][:r, :c].astype(jnp.float32) * np.float32(sw.scales[n])
-    arrs.update(b_z=bz_ref[...][:H], b_h=bh_ref[...][:H],
-                sig_lut=sig_ref[...], tanh_lut=tanh_ref[...])
-
-    x = x_ref[...][:, :d]
-    h = h_ref[...][:, :H]
-    h_new = qstep.step_batched(jnp, arrs, sw, h, x)
-    h_new = jnp.where(mask_ref[...][:, None] != 0, h_new, h)
-    out_ref[...] = jnp.pad(h_new, ((0, 0), (0, out_ref.shape[1] - H)))
+def _matvec_lanes(a_t, x, n: int):
+    """``qstep.matvec_batched`` in the lane layout: out[b, i] = sum_j
+    A[i, j] * x[b, j] over the n real j, ascending; ``a_t`` is A^T padded
+    to (128, 128), so padded output lanes accumulate exact zeros."""
+    out = jnp.zeros(x.shape, jnp.float32)
+    for j in range(n):
+        out = out + x[:, j:j + 1] * a_t[j:j + 1, :]
+    return out
 
 
-def _q15_step_kernel_mxu(sig_ref, tanh_ref, x_ref, h_ref, mask_ref,
-                         w_ref, u_ref, bz_ref, bh_ref, out_ref,
-                         *, zeta: float, nu: float):
-    """MXU-shaped variant of the batched single step: x/h stay in the full
-    128-lane padded layout and the two projections run as real
-    (B_TILE, 128) x (128, 128) contractions — one MXU pass each on TPU —
-    against *pre-dequantized, pre-multiplied* effective W^T/U^T (f32).
-
-    Padded lanes are inert by construction: effective-weight rows/columns
-    beyond (H, d) are zero, so ``pre`` is 0 there; the gate combine then
-    yields ``z * h = 0.5-ish * 0 = 0`` for padded h lanes (h enters padded
-    as zero every call — the resident wrapper in ops.py re-pads from the
-    (S, H) state), and the caller slices back to ``[:S, :H]``.  Numerics:
-    the MXU dot sums in hardware order, so hidden states drift from the
-    bit-exact reference like the jit backend does (~1e-9/step); argmax
-    predictions agree (gated in tests/test_device_fleet.py)."""
-    size = sig_ref.shape[0]
-    lo, hi = qstep.INPUT_MIN, qstep.INPUT_MAX
-    inv_bw = size / (hi - lo)
-
-    def lut(table, v):
-        idx = jnp.clip(((v - lo) * inv_bw).astype(jnp.int32), 0, size - 1)
-        y = jnp.take(table, idx)
-        return jnp.where(v >= hi, table[size - 1],
-                         jnp.where(v <= lo, table[0], y))
-
-    h = h_ref[...]
-    pre = jnp.dot(x_ref[...], w_ref[...],
-                  preferred_element_type=jnp.float32) \
-        + jnp.dot(h, u_ref[...], preferred_element_type=jnp.float32)
-    z = lut(sig_ref[...], pre + bz_ref[...])
-    h_tilde = lut(tanh_ref[...], pre + bh_ref[...])
-    h_new = (zeta * (1.0 - z) + nu) * h_tilde + z * h
-    out_ref[...] = jnp.where(mask_ref[...][:, None] != 0, h_new, h)
+def _store(t, scale):
+    """``qstep.store_batched`` (Q15 activation-storage fake-quant)."""
+    if scale is None:
+        return t
+    q = jnp.clip(jnp.round(t / scale), -qstep.Q15_MAX - 1, qstep.Q15_MAX)
+    return q * scale
 
 
-def make_fastgrnn_step(sw: "qstep.StepWeights", *, hp: int = 128,
-                       interpret: bool = True, mxu: bool = False):
-    """Build the batched single-step callable: pads the weight tensors,
-    biases and LUTs to device layout ONCE (they are deployment constants —
-    this runs on every 50 Hz tick, so per-call re-padding would dominate)
-    and caches one ``pl.pallas_call`` per slot count.
-
-    ``mxu=False`` (default): int16 Q15 weights dequantized on use, sliced
-    to real dims, qstep's fixed-order matvec loops — the layout whose op
-    order matches the scalar reference.  ``mxu=True``: the 128-lane padded
-    layout — effective W^T/U^T pre-multiplied to dense f32 (hp, hp) and the
-    projections lowered as (B_TILE, hp) x (hp, hp) MXU contractions
-    (achieved-vs-peak reported via ``Q15StreamStep.roofline``).
-
-    Returns ``step(x, h, mask) -> h_new``: x (S, Dp), h (S, Hp), mask (S,)
-    int32, S % B_TILE == 0 (ops.py pads).  Lanes >= H of h_new are zero."""
-    d, H = sw.input_dim, sw.hidden_dim
-    names = qstep.LOW_RANK_NAMES if sw.low_rank else qstep.FULL_RANK_NAMES
-
-    def pad2(a):
-        a = np.asarray(a)
-        return jnp.asarray(np.pad(a, ((0, hp - a.shape[0]), (0, hp - a.shape[1]))))
-
-    def pad1(a):
-        a = np.asarray(a, np.float32)
-        return jnp.asarray(np.pad(a, (0, hp - a.shape[0])))
-
-    if mxu:
-        w_eff = (sw.w["W1"] @ sw.w["W2"].T if sw.low_rank
-                 else sw.w["W"]).astype(np.float32)          # (H, d)
-        u_eff = (sw.w["U1"] @ sw.w["U2"].T if sw.low_rank
-                 else sw.w["U"]).astype(np.float32)          # (H, H)
-        weight_ops = [pad2(w_eff.T), pad2(u_eff.T)]          # (hp, hp) f32
-        kernel = functools.partial(_q15_step_kernel_mxu,
-                                   zeta=float(sw.zeta), nu=float(sw.nu))
+def _q15_step_kernel(x_ref, h_ref, mask_ref, sig_ref, tanh_ref, *refs,
+                     sw: "qstep.StepWeights", plan, H: int):
+    """x, h: (B_TILE, 128); mask: (B_TILE, 1) int32; LUTs (2, 128); refs:
+    one int16 A^T block per ``plan`` entry (name, inner dim), then b:
+    (2, 128) rows [b_z, b_h], then out (B_TILE, 128)."""
+    *w_refs, b_ref, out_ref = refs
+    a_t = {n: ref[...].astype(jnp.float32) * np.float32(sw.scales[n])
+           for (n, _), ref in zip(plan, w_refs)}
+    inner = dict(plan)
+    mv = lambda n, v: _matvec_lanes(a_t[n], v, inner[n])
+    x, h = x_ref[...], h_ref[...]
+    if sw.low_rank:
+        pre = mv("W1", mv("W2", x)) + mv("U1", mv("U2", h))
     else:
-        weight_ops = [pad2(sw.q[n]) for n in names]          # int16 Q15
-        kernel = functools.partial(_q15_step_kernel, sw=sw, d=d, H=H)
-    consts = ([jnp.asarray(sw.sig_lut), jnp.asarray(sw.tanh_lut)],
-              weight_ops,
-              [pad1(sw.b_z), pad1(sw.b_h)])
-    calls: dict[tuple[int, int], "object"] = {}
+        pre = mv("W", x) + mv("U", h)
+    pre = _store(pre, sw.store_scale("pre"))
+    z = _store(lut_lookup(sig_ref[...], pre + b_ref[0:1, :]),
+               sw.store_scale("z"))
+    h_tilde = _store(lut_lookup(tanh_ref[...], pre + b_ref[1:2, :]),
+                     sw.store_scale("h_tilde"))
+    h_new = _store((sw.zeta * (1.0 - z) + sw.nu) * h_tilde + z * h,
+                   sw.store_scale("h"))
+    lane = jax.lax.broadcasted_iota(jnp.int32, h.shape, 1)
+    out_ref[...] = jnp.where((mask_ref[...] != 0) & (lane < H), h_new, h)
+
+
+def _step_plan(sw: "qstep.StepWeights"):
+    """(name, inner dim, A^T) per matvec of ``qstep.step_batched``."""
+    w = sw.q
+    if sw.low_rank:
+        return [("W2", w["W2"].shape[0], w["W2"]),
+                ("W1", w["W1"].shape[1], w["W1"].T),
+                ("U2", w["U2"].shape[0], w["U2"]),
+                ("U1", w["U1"].shape[1], w["U1"].T)]
+    return [("W", w["W"].shape[1], w["W"].T),
+            ("U", w["U"].shape[1], w["U"].T)]
+
+
+def step_constants(sw: "qstep.StepWeights") -> list[np.ndarray]:
+    """The step kernel's deployment constants in the lane layout, in call
+    order: both LUT blocks, one int16 A^T per matvec, biases (2, 128)."""
+    H = sw.hidden_dim
+    pad2 = lambda a: np.pad(a, ((0, LANES - a.shape[0]),
+                                (0, LANES - a.shape[1])))
+    biases = np.zeros((2, LANES), np.float32)
+    biases[0, :H], biases[1, :H] = sw.b_z, sw.b_h
+    return [np.asarray(lut_block(sw.sig_lut)),
+            np.asarray(lut_block(sw.tanh_lut)),
+            *[pad2(np.asarray(a)) for _, _, a in _step_plan(sw)], biases]
+
+
+def fastgrnn_step_call(sw: "qstep.StepWeights", S: int):
+    """The step ``pallas_call`` over S rows (S % B_TILE == 0): ``(x, h,
+    mask, *step_constants(sw)) -> h_new`` with x, h (S, 128) f32 and mask
+    (S, 1) int32.  Lanes >= H of h_new are zero; rows whose mask is 0
+    keep h bit-for-bit."""
+    consts = step_constants(sw)
+    kernel = functools.partial(
+        _q15_step_kernel, sw=sw, H=sw.hidden_dim,
+        plan=[(n, k) for n, k, _ in _step_plan(sw)])
+    full = lambda a: pl.BlockSpec(a.shape, lambda i: (0, 0))
+    rows = lambda w: pl.BlockSpec((B_TILE, w), lambda i: (i, 0))
+    return pl.pallas_call(
+        kernel,
+        grid=(S // B_TILE,),
+        in_specs=[rows(LANES), rows(LANES), rows(1), *map(full, consts)],
+        out_specs=rows(LANES),
+        out_shape=jax.ShapeDtypeStruct((S, LANES), jnp.float32),
+        interpret=interpret_mode(),
+    )
+
+
+def make_fastgrnn_step(sw: "qstep.StepWeights", *, device=None):
+    """Build the batched single-step callable: the constants are laid out
+    ONCE on ``device`` (None = the default device) — they are deployment
+    constants and this runs on every 50 Hz tick — and one
+    :func:`fastgrnn_step_call` is cached per slot count.  Returns
+    ``step(x, h, mask) -> h_new``; ``step.constants`` are the placed
+    constants."""
+    consts = [jax.device_put(c, device) for c in step_constants(sw)]
+    calls: dict[int, "object"] = {}
 
     def step(x, h, mask):
-        S, dp = x.shape
-        key = (S, dp)
-        if key not in calls:
-            full = lambda shape: pl.BlockSpec(shape, lambda b: (0,) * len(shape))
-            calls[key] = pl.pallas_call(
-                kernel,
-                grid=(S // B_TILE,),
-                in_specs=[
-                    full((qstep.LUT_SIZE,)), full((qstep.LUT_SIZE,)),
-                    pl.BlockSpec((B_TILE, dp), lambda b: (b, 0)),
-                    pl.BlockSpec((B_TILE, hp), lambda b: (b, 0)),
-                    pl.BlockSpec((B_TILE,), lambda b: (b,)),
-                    *[full((hp, hp)) for _ in weight_ops],
-                    full((hp,)), full((hp,)),
-                ],
-                out_specs=pl.BlockSpec((B_TILE, hp), lambda b: (b, 0)),
-                out_shape=jax.ShapeDtypeStruct((S, hp), jnp.float32),
-                interpret=interpret,
-            )
-        luts, w_in, biases = consts
-        return calls[key](*luts, x, h, mask, *w_in, *biases)
+        S = x.shape[0]
+        if S not in calls:
+            calls[S] = fastgrnn_step_call(sw, S)
+        return calls[S](x, h, mask, *consts)
 
+    step.constants = consts
     return step

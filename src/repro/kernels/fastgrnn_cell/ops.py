@@ -23,9 +23,8 @@ from repro.core import fastgrnn as fg
 from repro.core.lut import make_lut
 from repro.obs.transfers import TransferLedger
 from . import qstep
-from .kernel import fastgrnn_window, B_TILE
-
-HP = 128
+from .kernel import (B_TILE, LANES, fastgrnn_window, lut_block,
+                     make_fastgrnn_step)
 
 
 def _pad2(a, r, c):
@@ -37,7 +36,7 @@ def _pad1(a, n):
     return jnp.pad(jnp.asarray(a, jnp.float32), (0, n - a.shape[0]))
 
 
-def fastgrnn_window_kernel(params, xs, *, interpret: bool = True):
+def fastgrnn_window_kernel(params, xs):
     """xs: (T, B, d) -> (h_final (B, H), traj (T, B, H)) via the Pallas
     kernel, LUT-activated (nearest mode, matching the deployed C engine)."""
     T, B, d = xs.shape
@@ -49,14 +48,13 @@ def fastgrnn_window_kernel(params, xs, *, interpret: bool = True):
 
     bpad = -B % B_TILE
     xs_p = jnp.pad(jnp.asarray(xs, jnp.float32),
-                   ((0, 0), (0, bpad), (0, HP - d)))
+                   ((0, 0), (0, bpad), (0, LANES - d)))
+    b = jnp.stack([_pad1(params["b_z"], LANES), _pad1(params["b_h"], LANES),
+                   jnp.full((LANES,), zeta, jnp.float32),
+                   jnp.full((LANES,), nu, jnp.float32)])
     h, traj = fastgrnn_window(
-        jnp.asarray(make_lut("sigmoid")), jnp.asarray(make_lut("tanh")),
-        xs_p,
-        _pad2(W.T, HP, HP), _pad2(U.T, HP, HP),
-        _pad1(params["b_z"], HP), _pad1(params["b_h"], HP),
-        jnp.asarray([zeta, nu], jnp.float32),
-        T=T, interpret=interpret)
+        lut_block(make_lut("sigmoid")), lut_block(make_lut("tanh")),
+        xs_p, _pad2(W.T, LANES, LANES), _pad2(U.T, LANES, LANES), b)
     return h[:B, :H], traj[:, :B, :H]
 
 
@@ -82,8 +80,8 @@ class Q15StreamStep:
         ``a*b + c`` into FMAs (even through ``lax.optimization_barrier``),
         so hidden states drift ~1e-9/step from the reference; argmax
         predictions still agree in practice.
-      * ``"pallas"`` — the ``kernel.fastgrnn_step`` Pallas kernel
-        (interpret mode on CPU, compiled on TPU), dequantizing the int16
+      * ``"pallas"`` — the ``kernel.make_fastgrnn_step`` Pallas kernel
+        (compiled on a TPU, interpreted elsewhere), dequantizing the int16
         weights on use inside the kernel.
 
     All backends share the single generic op sequence in ``qstep.py``.
@@ -92,8 +90,7 @@ class Q15StreamStep:
     BACKENDS = ("exact", "jit", "pallas")
 
     def __init__(self, qp_or_sw, *, act_scales=None, naive_acts=False,
-                 backend: str = "exact", interpret: bool = True,
-                 device=None, mxu: bool = False):
+                 backend: str = "exact", device=None):
         if backend not in self.BACKENDS:
             raise ValueError(f"backend must be one of {self.BACKENDS}")
         if isinstance(qp_or_sw, qstep.StepWeights):
@@ -102,11 +99,6 @@ class Q15StreamStep:
             self.sw = qstep.StepWeights.from_quantized(
                 qp_or_sw, act_scales=act_scales, naive_acts=naive_acts)
         self.backend = backend
-        self.interpret = interpret
-        if mxu and backend != "pallas":
-            raise ValueError("mxu=True requires the pallas backend (the "
-                             "128-lane MXU layout is a Pallas lowering)")
-        self.mxu = bool(mxu)
         # host<->device byte accounting (always on — plain int adds); the
         # fleet/engine stats() surface this and the zero-copy regression
         # test reads it (see repro.obs.transfers)
@@ -135,9 +127,8 @@ class Q15StreamStep:
             self._resident_step = self._build_jit_resident()
             self._step = self._build_jit()
         else:
-            from .kernel import make_fastgrnn_step
-            self._pallas_step = make_fastgrnn_step(
-                self.sw, hp=HP, interpret=interpret, mxu=self.mxu)
+            self._pallas_step = make_fastgrnn_step(self.sw,
+                                                   device=self.device)
             self._step = self._step_pallas
             self._resident_step = self._build_pallas_resident()
         # device-side reset: jitted masked zero (no host h round-trip)
@@ -290,21 +281,16 @@ class Q15StreamStep:
         def f(h, x, active):
             S = h.shape[0]
             sp = -S % B_TILE
-            h_p = jnp.pad(h, ((0, sp), (0, HP - H)))
-            x_p = jnp.pad(x, ((0, sp), (0, HP - d)))
-            m_p = jnp.pad(active.astype(jnp.int32), (0, sp))
+            h_p = jnp.pad(h, ((0, sp), (0, LANES - H)))
+            x_p = jnp.pad(x, ((0, sp), (0, LANES - d)))
+            m_p = jnp.pad(active.astype(jnp.int32)[:, None], ((0, sp), (0, 0)))
             return pstep(x_p, h_p, m_p)[:S, :H]
 
         return f
 
-    def roofline(self, stream_steps_per_sec: float) -> dict:
-        """Achieved-vs-peak for the batched single step against the
-        ``launch/roofline.py`` hardware model (TPU v5e), at a measured
-        aggregate stream-step rate.  ``model`` counts the real (H, d)
-        cell's FLOPs; ``padded`` counts what the 128-lane MXU layout
-        actually issues — the gap is the padding tax the MXU trade
-        accepts to hit the systolic array."""
-        from repro.launch import roofline as rl
+    def work_per_stream_step(self) -> dict:
+        """Model FLOPs and steady-state HBM bytes of one stream-step,
+        counted from the cell's shapes (valid on any platform)."""
         sw = self.sw
         H, d = sw.hidden_dim, sw.input_dim
         if sw.low_rank:
@@ -312,26 +298,44 @@ class Q15StreamStep:
             mm = 2 * (d * rw + H * rw + H * ru + H * ru)
         else:
             mm = 2 * H * (d + H)
-        gates = 10 * H                       # gate combine + LUT indexing
-        flops = mm + gates
-        padded = 2 * 2 * HP * HP + 10 * HP   # two (hp, hp) contractions
-        # steady-state HBM traffic per stream-step: x in, h in + out
-        # (weights/LUTs are VMEM-resident for the whole dispatch)
-        bytes_per_step = 4 * (d + 2 * H)
-        achieved = flops * float(stream_steps_per_sec)
+        # + gate combine and LUT indexing; bytes: x in, h in + out (the
+        # weights and LUTs stay in VMEM for the whole dispatch)
+        return {"backend": self.backend,
+                "model_flops_per_stream_step": int(mm + 10 * H),
+                "hbm_bytes_per_stream_step": int(4 * (d + 2 * H))}
+
+    def roofline(self, stream_steps_per_sec: float) -> dict:
+        """:meth:`work_per_stream_step` at a measured stream-step rate,
+        against the peaks of the device this step dispatches to
+        (``launch/roofline.peaks``).  The exact backend and any CPU raise:
+        a host rate is not a device metric."""
+        from repro.launch import roofline as rl
+        kind = ("host NumPy" if self.backend == "exact"
+                else (self.device or jax.devices()[0]).device_kind)
+        pk = rl.peaks(kind)
+        work = self.work_per_stream_step()
+        rate = float(stream_steps_per_sec)
+        achieved = work["model_flops_per_stream_step"] * rate
         return {
-            "backend": self.backend,
-            "mxu": self.mxu,
-            "model_flops_per_stream_step": int(flops),
-            "padded_flops_per_stream_step": int(padded),
-            "hbm_bytes_per_stream_step": int(bytes_per_step),
-            "stream_steps_per_sec": float(stream_steps_per_sec),
-            "achieved_gflops": round(achieved / 1e9, 4),
-            "peak_fraction": achieved / rl.PEAK_FLOPS,
-            "memory_bound_stream_steps_per_sec": rl.HBM_BW / bytes_per_step,
-            "peak_flops": rl.PEAK_FLOPS,
-            "hbm_bw_bytes_per_sec": rl.HBM_BW,
+            **work,
+            "device_kind": kind,
+            "stream_steps_per_sec": rate,
+            "achieved_gflops": achieved / 1e9,
+            "peak_fraction": achieved / pk["flops"],
+            "memory_bound_stream_steps_per_sec":
+                pk["hbm_bw"] / work["hbm_bytes_per_stream_step"],
+            "peak_flops": pk["flops"],
+            "hbm_bw_bytes_per_sec": pk["hbm_bw"],
         }
+
+    def device_constants(self) -> list:
+        """The jax arrays a device backend dispatches against (weights,
+        biases, LUTs) — where they live is where the step runs."""
+        if self.backend == "jit":
+            return list(self._jnp_arrs.values())
+        if self.backend == "pallas":
+            return list(self._pallas_step.constants)
+        return []
 
     # -- one tick -----------------------------------------------------------
     def step(self, h, x, active):
@@ -418,12 +422,12 @@ class Q15StreamStep:
     def _step_pallas(self, h, x, active):
         S, H = h.shape
         sp = -S % B_TILE
-        h_p = np.zeros((S + sp, HP), np.float32)
+        h_p = np.zeros((S + sp, LANES), np.float32)
         h_p[:S, :H] = h
-        x_p = np.zeros((S + sp, HP), np.float32)
+        x_p = np.zeros((S + sp, LANES), np.float32)
         x_p[:S, :x.shape[1]] = x
-        m_p = np.zeros((S + sp,), np.int32)
-        m_p[:S] = active
+        m_p = np.zeros((S + sp, 1), np.int32)
+        m_p[:S, 0] = active
         # host-staged path: full padded h round-trip per tick (cf. the
         # zero-h-copy device-resident step_resident)
         self.transfers.h2d(x_p.nbytes + m_p.nbytes)

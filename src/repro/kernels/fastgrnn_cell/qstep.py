@@ -7,7 +7,9 @@ runs as
 
   * vectorized NumPy        (``xp=numpy`` — the *exact* backend),
   * eager / jit jax.numpy   (``xp=jax.numpy``),
-  * the Pallas kernel body  (``xp=jax.numpy`` inside ``pl.pallas_call``).
+
+and ``kernel.py`` restates the same op sequence in the 128-lane layout
+for the Pallas step (bitwise equal to the NumPy path on a TPU v5e).
 
 Bit-stability contract (paper Sec. IV-D / Table VI, lifted to batch scale):
 every function here is the batched image of the scalar reference in
@@ -30,7 +32,7 @@ import numpy as np
 from repro.core.lut import make_lut, LUT_SIZE, INPUT_MIN, INPUT_MAX
 from repro.core.quantization import QuantizedParams, Q15_MAX
 
-_INV_BW = LUT_SIZE / (INPUT_MAX - INPUT_MIN)   # exact python float (16.0)
+INV_BW = LUT_SIZE / (INPUT_MAX - INPUT_MIN)   # exact python float (16.0)
 
 LOW_RANK_NAMES = ("W1", "W2", "U1", "U2")
 FULL_RANK_NAMES = ("W", "U")
@@ -131,7 +133,7 @@ def matvec_batched(xp, A, x):
 
 def lut_eval_batched(xp, table, v):
     """Nearest-bucket LUT over (B, H), identical to qruntime._lut_eval_scalar."""
-    idx = xp.clip(((v - INPUT_MIN) * _INV_BW).astype(xp.int32), 0, LUT_SIZE - 1)
+    idx = xp.clip(((v - INPUT_MIN) * INV_BW).astype(xp.int32), 0, LUT_SIZE - 1)
     y = table[idx]
     y = xp.where(v >= INPUT_MAX, table[LUT_SIZE - 1], y)
     y = xp.where(v <= INPUT_MIN, table[0], y)

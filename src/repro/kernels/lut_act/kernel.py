@@ -2,13 +2,11 @@
 
 MCU -> TPU adaptation (DESIGN.md Sec. 2): the table lives in Flash on the
 MSP430 and is re-read per call; here it is pinned in VMEM for the whole
-tile sweep and the lookup vectorizes on the VPU.  On TPU the win is
-determinism/precision control rather than speed — quantified in
-benchmarks/lut_speedup.py.
+tile sweep and the lookup vectorizes on the VPU (on TPU the win is
+determinism/precision control, not speed).
 
-Tiling: the input is processed in (BLOCK_R, 128) VMEM tiles (lane dim 128
-hardware-aligned); the 256 x f32 table (1 KB) is replicated to every grid
-step via a constant index_map.
+Tiling: (BLOCK_R, 128) VMEM tiles of the input; the 256 x f32 table (1 KB)
+is replicated to every grid step via a constant index_map.
 """
 from __future__ import annotations
 
@@ -17,6 +15,8 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from repro.kernels import interpret_mode
 
 BLOCK_R = 256          # sublane-dim tile rows
 BLOCK_C = 128          # lane dim (VPU width)
@@ -46,9 +46,9 @@ def _lut_kernel(table_ref, x_ref, o_ref, *, lo: float, hi: float,
 
 # detlint: ignore[det-jit-pallas] fixed block-padded shapes (ops.py pads pre-call); tolerance-gated, not bit-exact
 @functools.partial(jax.jit, static_argnames=("lo", "hi", "mode",
-                                             "linear_tail", "interpret"))
+                                             "linear_tail"))
 def lut_act_2d(table, x2d, *, lo: float, hi: float, mode: str = "nearest",
-               linear_tail: bool = False, interpret: bool = True):
+               linear_tail: bool = False):
     """x2d: (R, C) padded to (BLOCK_R, BLOCK_C) multiples by ops.py."""
     r, c = x2d.shape
     grid = (r // BLOCK_R, c // BLOCK_C)
@@ -62,5 +62,5 @@ def lut_act_2d(table, x2d, *, lo: float, hi: float, mode: str = "nearest",
         ],
         out_specs=pl.BlockSpec((BLOCK_R, BLOCK_C), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct(x2d.shape, x2d.dtype),
-        interpret=interpret,
+        interpret=interpret_mode(),
     )(table, x2d)

@@ -12,8 +12,7 @@ _LINEAR_TAILS = ("silu", "gelu", "softplus")
 
 
 def lut_act(x, fn: str = "tanh", *, mode: str = "nearest",
-            lo: float = INPUT_MIN, hi: float = INPUT_MAX,
-            interpret: bool = True):
+            lo: float = INPUT_MIN, hi: float = INPUT_MAX):
     table = jnp.asarray(make_lut(fn, 256, lo, hi))
     flat = x.reshape(-1)
     n = flat.shape[0]
@@ -24,7 +23,7 @@ def lut_act(x, fn: str = "tanh", *, mode: str = "nearest",
     flat = jnp.pad(flat, (0, total - n))
     x2d = flat.reshape(rows + rpad, cols)
     y = lut_act_2d(table, x2d, lo=lo, hi=hi, mode=mode,
-                   linear_tail=(fn in _LINEAR_TAILS), interpret=interpret)
+                   linear_tail=(fn in _LINEAR_TAILS))
     return y.reshape(-1)[:n].reshape(x.shape)
 
 

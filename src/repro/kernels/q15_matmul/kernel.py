@@ -5,11 +5,9 @@ The paper's Appendix-B runtime dequantizes each int16 weight on use
 Sec. 2): weights stream HBM->VMEM as int8/int16 (2-4x fewer HBM bytes than
 f32 — decode is HBM-bound, so this moves the dominant roofline term
 directly), convert to bf16 INSIDE the VMEM tile, hit the MXU, and apply
-the per-tensor scale once to the f32 accumulator on the way out (the
-scale commutes with the contraction).
+the per-tensor scale once to the f32 accumulator on the way out.
 
-Grid (M/bm, N/bn, K/bk), K innermost; f32 accumulation in a VMEM scratch
-tile across the K steps.
+Grid (M/bm, N/bn, K/bk), K innermost; f32 accumulation in VMEM scratch.
 """
 from __future__ import annotations
 
@@ -19,6 +17,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels import interpret_mode
 
 BM, BN, BK = 128, 128, 128
 
@@ -39,9 +39,8 @@ def _mm_kernel(x_ref, w_ref, scale_ref, o_ref, acc_ref, *, n_k: int):
         o_ref[...] = (acc_ref[...] * scale_ref[0]).astype(o_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("interpret", "out_dtype"))  # detlint: ignore[det-jit-pallas] fixed block-padded shapes (ops.py pads pre-call); tolerance-gated, not bit-exact
-def q15_matmul_padded(x, wq, scale, *, out_dtype=jnp.float32,
-                      interpret: bool = True):
+@functools.partial(jax.jit, static_argnames=("out_dtype",))  # detlint: ignore[det-jit-pallas] fixed block-padded shapes (ops.py pads pre-call); tolerance-gated, not bit-exact
+def q15_matmul_padded(x, wq, scale, *, out_dtype=jnp.float32):
     """x: (M, K) bf16/f32; wq: (K, N) int8/int16; scale: (1,) f32.
     M, N, K must be multiples of the block sizes (ops.py pads)."""
     m, k = x.shape
@@ -58,5 +57,5 @@ def q15_matmul_padded(x, wq, scale, *, out_dtype=jnp.float32,
         out_specs=pl.BlockSpec((BM, BN), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
         scratch_shapes=[pltpu.VMEM((BM, BN), jnp.float32)],
-        interpret=interpret,
+        interpret=interpret_mode(),
     )(x, wq, scale)

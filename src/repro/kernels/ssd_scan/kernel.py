@@ -3,12 +3,10 @@
 One program per (batch, head) pair; the kernel walks the chunk sequence
 with a fori_loop, holding the running (N, P) state in a VMEM scratch —
 the inter-chunk recurrence never touches HBM.  Per chunk the intra-chunk
-term is the masked decay-weighted (Q, Q) matmul pair (MXU work), matching
-models/mamba2.ssd_chunked exactly.
+term is the masked decay-weighted (Q, Q) matmul pair (MXU work).
 
 Layout per program: x (S, P), dt (S, 1), B/C (S, N) for ONE head (groups
-are pre-broadcast by ops.py).  Q (chunk) is a multiple of 8; N, P are
-128-lane-aligned by ops.py padding.
+pre-broadcast by ops.py); Q (chunk) a multiple of 8, N, P 128-lane padded.
 """
 from __future__ import annotations
 
@@ -18,6 +16,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels import interpret_mode
 
 
 def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, hout_ref,
@@ -63,8 +63,8 @@ def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, hout_ref,
     hout_ref[...] = state_ref[...]
 
 
-@functools.partial(jax.jit, static_argnames=("chunk", "interpret"))  # detlint: ignore[det-jit-pallas] fixed chunk-padded shapes (ops.py pads pre-call); tolerance-gated, not bit-exact
-def ssd_scan_heads(x, dt, A, B, C, *, chunk: int = 64, interpret: bool = True):
+@functools.partial(jax.jit, static_argnames=("chunk",))  # detlint: ignore[det-jit-pallas] fixed chunk-padded shapes (ops.py pads pre-call); tolerance-gated, not bit-exact
+def ssd_scan_heads(x, dt, A, B, C, *, chunk: int = 64):
     """Per-head layout: x (BH, S, P); dt (BH, S, 1); A (BH, 1); B/C
     (BH, S, N).  S % chunk == 0 (ops.py pads).  Returns (y, final_state)."""
     bh, s, p = x.shape
@@ -90,5 +90,5 @@ def ssd_scan_heads(x, dt, A, B, C, *, chunk: int = 64, interpret: bool = True):
             jax.ShapeDtypeStruct((bh, n, p), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((n, p), jnp.float32)],
-        interpret=interpret,
+        interpret=interpret_mode(),
     )(x, dt, A, B, C)

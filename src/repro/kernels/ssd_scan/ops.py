@@ -7,7 +7,7 @@ import jax.numpy as jnp
 from .kernel import ssd_scan_heads
 
 
-def ssd_scan(x, dt, A, B, C, *, chunk: int = 64, interpret: bool = True):
+def ssd_scan(x, dt, A, B, C, *, chunk: int = 64):
     """Same signature/semantics as models.mamba2.ssd_chunked (h0=None).
     x: (b,S,H,P); dt: (b,S,H); A: (H,); B,C: (b,S,G,N)."""
     b, s, h, p = x.shape
@@ -25,8 +25,7 @@ def ssd_scan(x, dt, A, B, C, *, chunk: int = 64, interpret: bool = True):
     Bh = jnp.moveaxis(Bh, 2, 1).reshape(b * h, sp, n)
     Ch = jnp.moveaxis(Ch, 2, 1).reshape(b * h, sp, n)
     Ah = jnp.tile(A.astype(jnp.float32), b).reshape(b * h, 1)
-    y, hf = ssd_scan_heads(xh, dth, Ah, Bh, Ch, chunk=chunk,
-                           interpret=interpret)
+    y, hf = ssd_scan_heads(xh, dth, Ah, Bh, Ch, chunk=chunk)
     y = jnp.moveaxis(y.reshape(b, h, sp, p), 1, 2)[:, :s]
     state = hf.reshape(b, h, n, p)
     return y.astype(x.dtype), state

@@ -135,6 +135,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool, *,
             cost, shape.kind, pods=pods, data=data, model=model,
             collective_bytes_per_device=float(colls.total_bytes),
             model_flops_global=registry.step_flops_model(cfg, shape),
+            device_kind="TPU v5 lite",   # the production meshes: v5e pods
             weight_shards=1 if seqp else None)
         rec["parallel_mode"] = mode
         rec.update(

@@ -13,16 +13,30 @@ HLO and sum the operand sizes of every all-gather / all-reduce /
 reduce-scatter / all-to-all / collective-permute instruction (two-pass
 parse: instruction-name -> shape table, then operand lookup).
 
-Hardware model: TPU v5e — 197 TFLOP/s bf16, 819 GB/s HBM, ~50 GB/s/link ICI.
+Hardware model: the per-chip peaks of :data:`PEAKS`, looked up by the
+``device_kind`` the program is sized for.
 """
 from __future__ import annotations
 
 import dataclasses
 import re
 
-PEAK_FLOPS = 197e12          # bf16 / chip
-HBM_BW = 819e9               # B/s / chip
-ICI_BW = 50e9                # B/s / link
+#: Published per-chip peaks keyed by ``jax.Device.device_kind``.  Source:
+#: Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16, HBM at
+#: 819 GB/s, 1,600 Gbit/s chip-to-chip interconnect over 4 links.
+PEAKS = {
+    "TPU v5 lite": {"flops": 197e12, "hbm_bw": 819e9, "ici_bw": 50e9},
+}
+
+
+def peaks(device_kind: str) -> dict:
+    """Peaks of one chip of ``device_kind``; a kind without published peaks
+    (a CPU among them) raises instead of borrowing another chip's."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(f"no published peaks for device kind "
+                         f"{device_kind!r} (known: {sorted(PEAKS)})") from None
 
 _DTYPE_BYTES = {
     "f64": 8, "f32": 4, "f16": 2, "bf16": 2, "f8e4m3fn": 1, "f8e5m2": 1,
@@ -176,11 +190,12 @@ class Roofline:
     collective_bytes_per_device: float
     model_flops_global: float
     chips: int
+    device_kind: str
 
     @classmethod
     def from_cost(cls, cost, kind: str, *, pods: int, data: int, model: int,
                   collective_bytes_per_device: float,
-                  model_flops_global: float,
+                  model_flops_global: float, device_kind: str,
                   weight_shards: int | None = None) -> "Roofline":
         """Build roofline terms from the analytic CellCost + parsed
         collectives, applying the sharding split factors:
@@ -207,19 +222,24 @@ class Roofline:
             collective_bytes_per_device=collective_bytes_per_device,
             model_flops_global=model_flops_global,
             chips=chips,
+            device_kind=device_kind,
         )
 
     @property
+    def peaks(self) -> dict:
+        return peaks(self.device_kind)
+
+    @property
     def t_compute(self) -> float:
-        return self.flops_per_device / PEAK_FLOPS
+        return self.flops_per_device / self.peaks["flops"]
 
     @property
     def t_memory(self) -> float:
-        return self.bytes_per_device / HBM_BW
+        return self.bytes_per_device / self.peaks["hbm_bw"]
 
     @property
     def t_collective(self) -> float:
-        return self.collective_bytes_per_device / ICI_BW
+        return self.collective_bytes_per_device / self.peaks["ici_bw"]
 
     @property
     def bottleneck(self) -> str:
@@ -241,7 +261,8 @@ class Roofline:
     def roofline_fraction(self) -> float:
         """Achievable fraction of the compute roofline at the bound:
         useful-FLOPs time / bound time."""
-        t_useful = self.model_flops_global / (self.chips * PEAK_FLOPS)
+        t_useful = self.model_flops_global / (self.chips
+                                              * self.peaks["flops"])
         return t_useful / self.t_bound if self.t_bound else 0.0
 
     def row(self) -> dict:

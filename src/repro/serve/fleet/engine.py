@@ -176,8 +176,7 @@ class FleetEngine:
             dev: Q15StreamStep(self.qp, act_scales=act_scales,
                                naive_acts=naive_acts,
                                backend=config.stream.backend,
-                               interpret=config.stream.interpret,
-                               device=dev, mxu=config.stream.mxu)
+                               device=dev)
             for dev, _ in groups}
         self._devices = devices
         # device-resident fused ticks: h lives on device between ticks
@@ -942,6 +941,22 @@ class FleetEngine:
     _SCHED_KEYS = ("active", "pending", "peak_active", "admissions",
                    "recycles", "spills", "completed", "cancelled",
                    "evictions", "ticks")
+
+    def shard_placement(self) -> list[dict[str, Any]]:
+        """Where each shard runs: its assigned device (None = default
+        device / host), the devices holding its resident h (empty on the
+        host path), and those holding the weight/LUT constants of both
+        its own kernel and its device group's fused kernel."""
+        out = []
+        for i, sh in enumerate(self.shards):
+            h = sh._resolve_h() if sh._device_resident else None
+            consts = (sh.kernel.device_constants()
+                      + self._group_of[i].kernel.device_constants())
+            out.append({"device": self._devices[i],
+                        "h": set() if h is None else set(h.devices()),
+                        "constants": set().union(
+                            *(c.devices() for c in consts))})
+        return out
 
     def stats(self) -> dict[str, Any]:
         """Fleet-wide roll-up: every scheduler/workload counter summed

@@ -9,9 +9,8 @@ the fleet runs everywhere tier-1 runs.
 Placement modes:
 
 * ``"auto"``    — distinct devices if the backend is jit/pallas and more
-  than one jax device exists; host fallback otherwise.
-* ``"devices"`` — force round-robin device assignment (raises if jax has
-  no devices at all).
+  than one jax device exists; the default device otherwise.
+* ``"devices"`` — force round-robin device assignment.
 * ``"host"``    — everything on the default device / process-local NumPy.
   This is also the mode under which tick fusion batches every shard into
   ONE kernel dispatch (see ``fleet.engine``), which on a small-core host
@@ -21,6 +20,8 @@ Placement modes:
 from __future__ import annotations
 
 from typing import Any
+
+import jax
 
 PLACEMENTS = ("auto", "devices", "host")
 
@@ -36,15 +37,7 @@ def shard_devices(n_shards: int, placement: str = "auto",
         raise ValueError(f"placement must be one of {PLACEMENTS}")
     if placement == "host" or backend == "exact":
         return [None] * n_shards
-    try:
-        import jax
-        devs = jax.devices()
-    except Exception:
-        devs = []
-    if not devs:
-        if placement == "devices":
-            raise ValueError("placement='devices' but jax has no devices")
-        return [None] * n_shards
+    devs = jax.devices()
     if placement == "auto" and len(devs) < 2:
         return [None] * n_shards
     return [devs[i % len(devs)] for i in range(n_shards)]

@@ -73,8 +73,6 @@ class StreamingConfig:
     sample_rate_hz: float = 50.0
     reset_on_emit: bool = True   # tumbling windows (matches QRuntime.predict)
     backend: str = "exact"       # "exact" | "jit" | "pallas"
-    interpret: bool = True       # pallas backend: interpret mode (CPU)
-    mxu: bool = False            # pallas: 128-lane MXU matmul layout
     device: Any = None           # jax device for jit/pallas dispatch (fleet
     # shard placement); None = default device / process-local NumPy
     device_resident: Any = "auto"   # keep the hidden-state table on device
@@ -231,9 +229,7 @@ class StreamingEngine:
         self.kernel = Q15StreamStep(self.qp, act_scales=act_scales,
                                     naive_acts=naive_acts,
                                     backend=config.backend,
-                                    interpret=config.interpret,
-                                    device=config.device,
-                                    mxu=config.mxu)
+                                    device=config.device)
         if config.device_resident == "auto":
             self._device_resident = self.kernel.device_state_profitable
         else:

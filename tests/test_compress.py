@@ -29,7 +29,6 @@ from repro.compress import (CalibrateActivations, IHTSparsify, LowRankFactor,
                             ModelArtifact, PackLUT, Pipeline, QuantizePTQ,
                             default_deploy_pipeline, dequantize_tree,
                             pipeline_from_config, quantize_tree)
-from repro.core import fastgrnn as fg
 from repro.core.qruntime import QRuntime, calibrate, calibrate_deploy
 from repro.core.quantization import QuantConfig, quantize_params
 from repro.data import hapt
@@ -39,10 +38,8 @@ GOLDEN_PATH = os.path.join(os.path.dirname(__file__), "goldens",
 
 
 def _params(seed=0, low_rank=True):
-    import jax
-    cfg = fg.FastGRNNConfig(rank_w=2 if low_rank else None,
-                            rank_u=8 if low_rank else None)
-    return fg.init_params(cfg, jax.random.PRNGKey(seed))
+    from repro.deploy.goldens import reference_params
+    return reference_params(seed, low_rank)
 
 
 @pytest.fixture(scope="module")

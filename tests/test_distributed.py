@@ -7,37 +7,24 @@ Two tiers:
   the pytest process itself has 8 host devices: device enumeration,
   per-device placement of jitted compute (the substrate the fleet's
   per-shard device placement rides — see tests/test_fleet.py), and a
-  pmap collective.  These run on every jax this repo supports.
+  pmap collective.
 * **Explicit-sharding suite** (subprocess, slow) — verifies:
   sharded train step == single-device step numerically; vocab-parallel
   CE == plain CE; int8/bf16 compressed psum + error feedback; GPipe
   pipeline == sequential stages; checkpoint resharding across mesh
-  shapes.  Drives the modern explicit-sharding APIs (jax.make_mesh with
-  axis_types, jax.sharding.AxisType, top-level jax.shard_map); on
-  containers pinned to older jax (e.g. 0.4.x) those tests — and only
-  those — skip.
+  shapes.  Drives the explicit-sharding APIs (jax.make_mesh with
+  axis_types, jax.sharding.AxisType, top-level jax.shard_map).
 """
 import jax
 import jax.numpy as jnp
-import jax.sharding
 import numpy as np
 import pytest
 
 from conftest import run_subprocess
 
-_MISSING = [name for name, ok in [
-    ("jax.sharding.AxisType", hasattr(jax.sharding, "AxisType")),
-    ("jax.shard_map", hasattr(jax, "shard_map")),
-    ("jax.make_mesh", hasattr(jax, "make_mesh")),
-] if not ok]
-needs_explicit_sharding = pytest.mark.skipif(
-    bool(_MISSING),
-    reason=f"jax {jax.__version__} lacks {', '.join(_MISSING)} "
-           "(multi-host sharding suite needs the explicit-sharding APIs)")
-
 
 # ---------------------------------------------------------------------------
-# In-process multi-device smokes (every supported jax; not slow)
+# In-process multi-device smokes (not slow)
 # ---------------------------------------------------------------------------
 
 def test_host_devices_forced_in_process():
@@ -71,7 +58,6 @@ def test_pmap_collective_across_host_devices():
 # Explicit-sharding suite (subprocess; needs modern jax APIs)
 # ---------------------------------------------------------------------------
 
-@needs_explicit_sharding
 @pytest.mark.slow
 def test_sharded_train_step_matches_single_device():
     out = run_subprocess("""
@@ -116,7 +102,6 @@ print("OK")
     assert "OK" in out
 
 
-@needs_explicit_sharding
 @pytest.mark.slow
 def test_compressed_psum_error_feedback():
     out = run_subprocess("""
@@ -146,7 +131,6 @@ print("OK")
     assert "OK" in out
 
 
-@needs_explicit_sharding
 @pytest.mark.slow
 def test_vocab_parallel_ce_matches_plain():
     out = run_subprocess("""
@@ -176,7 +160,6 @@ print("OK")
     assert "OK" in out
 
 
-@needs_explicit_sharding
 @pytest.mark.slow
 def test_pipeline_matches_sequential():
     out = run_subprocess("""
@@ -203,7 +186,6 @@ print("OK")
     assert "OK" in out
 
 
-@needs_explicit_sharding
 @pytest.mark.slow
 def test_checkpoint_elastic_resharding():
     out = run_subprocess("""
@@ -227,7 +209,6 @@ print("OK")
     assert "OK" in out
 
 
-@needs_explicit_sharding
 @pytest.mark.slow
 def test_sp_dense_and_splitkv_match_reference():
     out = run_subprocess("""
