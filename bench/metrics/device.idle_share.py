@@ -1,0 +1,9 @@
+"""Percent of the traced window in which no operation ran on the device,
+averaged over the chips.  Device trace."""
+
+
+def read(ctx):
+    tr = ctx["trace"]
+    if not tr or not tr["devices"] or tr["window_s"] <= 0:
+        return None
+    return 100.0 * (1.0 - tr["busy_s"] / tr["window_s"])
