@@ -227,12 +227,10 @@ def main() -> None:
           f"{capacity['realtime_streams_50hz']:,} real-time 50 Hz sensors "
           f"(sustained: {capacity['sustained_realtime_50hz']})", flush=True)
 
-    # work per stream-step from the cell's shapes; achieved-vs-peak only
-    # where the step ran on a device with published peaks (a CPU rate is
-    # not a device metric, so a CPU run records the counts alone)
+    # work per stream-step from the cell's shapes (a host rate is not a
+    # device metric: the kernel's roofline share comes from the device
+    # trace, bench/metrics/q15_step_roofline.py)
     kern = Q15StreamStep(qp, backend=args.backend)
-    rate = capacity["stream_steps_per_sec"]
-    on_chip = args.backend != "exact" and jax.default_backend() == "tpu"
     record = {
         "benchmark": "fleet_sharding",
         "model": "FastGRNN H=16 r_w=2 r_u=8, Q15 PTQ (566-byte class)",
@@ -254,8 +252,7 @@ def main() -> None:
             p: max(r["scaling_x"] for r in rows if r["placement"] == p)
             for p in resolved},
         "capacity": capacity,
-        "kernel_roofline": (kern.roofline(rate) if on_chip
-                            else kern.work_per_stream_step()),
+        "kernel_roofline": kern.work_per_stream_step(),
     }
     with open(args.out, "w") as f:
         json.dump(record, f, indent=2)

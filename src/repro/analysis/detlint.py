@@ -38,6 +38,7 @@ ORDERED_PATHS = ("serve/", "obs/")
 
 #: Receiver names that identify tracer objects at span call sites.
 _TRACER_NAMES = ("tr", "tracer", "_tracer")
+_SPAN_OPENERS = ("open", "open_count")
 
 _SUPPRESS_RE = re.compile(
     r"#\s*detlint:\s*ignore\[([a-z0-9\-, ]+)\]\s*(.*)$")
@@ -229,11 +230,11 @@ def _walk_own(fn: ast.AST):
 
 
 def _check_span_pairing(tree: ast.AST, path: str):
-    """PR 7: spans are recorded as a ``t0 = tracer.t()`` /
-    ``tracer.rec(phase, t0)`` pair.  A ``t()`` whose result is never
-    passed to ``rec`` is a dropped span (latency silently missing from
-    the phase breakdown), and a non-literal phase defeats the static
-    registry check."""
+    """PR 7: spans are recorded as a ``tok = tracer.open(phase)`` /
+    ``tracer.close(tok)`` pair (``open_count`` likewise).  A token that
+    is never passed to ``close`` is a dropped span (latency silently
+    missing from the phase breakdown), and a non-literal phase defeats
+    the static registry check."""
     for fn in _function_scopes(tree):
         starts: dict[str, int] = {}
         consumed: set[str] = set()
@@ -241,30 +242,30 @@ def _check_span_pairing(tree: ast.AST, path: str):
             if (isinstance(node, ast.Assign)
                     and isinstance(node.value, ast.Call)
                     and isinstance(node.value.func, ast.Attribute)
-                    and node.value.func.attr == "t"
-                    and not node.value.args
+                    and node.value.func.attr in _SPAN_OPENERS
                     and _is_tracer_recv(node.value.func)):
                 for tgt in node.targets:
                     if isinstance(tgt, ast.Name):
                         starts[tgt.id] = node.lineno
             if (isinstance(node, ast.Call)
                     and isinstance(node.func, ast.Attribute)
-                    and node.func.attr in ("rec", "span")
                     and _is_tracer_recv(node.func)):
                 args = node.args
-                if not args or not (isinstance(args[0], ast.Constant)
-                                    and isinstance(args[0].value, str)):
+                if (node.func.attr in _SPAN_OPENERS + ("span",)
+                        and not (args and isinstance(args[0], ast.Constant)
+                                 and isinstance(args[0].value, str))):
                     yield (node.lineno,
                            f"span phase passed to .{node.func.attr}() must "
                            f"be a string literal (registry-checkable)")
-                if (node.func.attr == "rec" and len(args) >= 2
-                        and isinstance(args[1], ast.Name)):
-                    consumed.add(args[1].id)
+                if (node.func.attr == "close" and args
+                        and isinstance(args[0], ast.Name)):
+                    consumed.add(args[0].id)
         for name, line in sorted(starts.items()):
             if name not in consumed:
                 yield (line,
-                       f"span start {name} = tracer.t() is never passed to "
-                       f"tracer.rec(...) in {fn.name}() — dropped span")
+                       f"span token {name} = tracer.open(...) is never "
+                       f"passed to tracer.close(...) in {fn.name}() — "
+                       f"dropped span")
 
 
 def _check_span_registry(tree: ast.AST, path: str):
@@ -274,7 +275,7 @@ def _check_span_registry(tree: ast.AST, path: str):
     for node in ast.walk(tree):
         if (isinstance(node, ast.Call)
                 and isinstance(node.func, ast.Attribute)
-                and node.func.attr in ("rec", "span")
+                and node.func.attr in _SPAN_OPENERS + ("span",)
                 and _is_tracer_recv(node.func)
                 and node.args
                 and isinstance(node.args[0], ast.Constant)
@@ -364,7 +365,7 @@ CHECKS: tuple[Check, ...] = (
           "no unordered set iteration in dispatch/stats paths (PR 5/7)",
           _ordered_paths, _check_set_iteration),
     Check("det-span-pairing",
-          "t()/rec() spans paired, phases literal (PR 7)",
+          "open()/close() spans paired, phases literal (PR 7)",
           _span_paths, _check_span_pairing),
     Check("det-span-registry",
           "span phases drawn from repro.obs.phases.PHASES (PR 7)",

@@ -131,13 +131,13 @@ _DET_SOURCES: dict[str, tuple[str, str]] = {
         "    return [s for s in set(shards)]\n")),
     "det-span-pairing": ("serve/fx.py", (
         "def tick(self, tr):\n"
-        "    t0 = tr.t()\n"
+        "    t0 = tr.open('fleet.dispatch')\n"
         "    self.work()\n")),
     "det-span-registry": ("serve/fx.py", (
         "def tick(self, tr):\n"
-        "    t0 = tr.t()\n"
+        "    t0 = tr.open('fleet.dispach')\n"
         "    self.work()\n"
-        "    tr.rec('fleet.dispach', t0)\n")),
+        "    tr.close(t0)\n")),
     "det-conserved-counters": ("serve/fleet/engine.py", (
         "class FleetEngine:\n"
         "    def __init__(self):\n"

@@ -116,7 +116,7 @@ def run_parity(img, qp=None, windows: np.ndarray | None = None, *,
         raise TypeError("run_parity needs (artifact, windows=...) or "
                         "(image, qp, windows)")
     tr = Tracer(capacity=64) if tracer is None else tracer
-    t_total = tr.t()
+    t_total = tr.open("verify.total")
     n_trace = min(n_trace, len(windows))
     n_scalar = min(n_scalar, len(windows))
     vm = QVM(img)
@@ -241,7 +241,7 @@ def run_parity(img, qp=None, windows: np.ndarray | None = None, *,
                       for name, secs in tr.totals_s().items()
                       if name.startswith("verify.")
                       and name != "verify.total"},
-        "total_s": round(tr.rec("verify.total", t_total) / 1e9, 3),
+        "total_s": round(tr.close(t_total) / 1e9, 3),
     }
     if numerics is not None:
         report["numerics"] = numerics

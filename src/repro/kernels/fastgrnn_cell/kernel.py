@@ -93,6 +93,7 @@ def fastgrnn_window(sig_lut, tanh_lut, x, w_t, u_t, b):
             jax.ShapeDtypeStruct((Tn, B, Hp), jnp.float32),
         ],
         interpret=interpret_mode(),
+        name="q15_window",
     )(sig_lut, tanh_lut, x, w_t, u_t, b)
 
 
@@ -196,6 +197,7 @@ def fastgrnn_step_call(sw: "qstep.StepWeights", S: int):
         out_specs=rows(LANES),
         out_shape=jax.ShapeDtypeStruct((S, LANES), jnp.float32),
         interpret=interpret_mode(),
+        name="q15_step",
     )
 
 

@@ -207,8 +207,20 @@ class Q15StreamStep:
     def rows_to_host(self, h_dev, rows) -> np.ndarray:
         """Pull only ``rows`` of the resident state to host (emission,
         taps, lazy snapshots) — a (k, H) d2h instead of the full table."""
-        rows = np.asarray(rows)
-        out = np.array(h_dev[rows], np.float32)
+        return self.rows_fetch(self.rows_issue(h_dev, rows))
+
+    def rows_issue(self, h_dev, rows):
+        """First half of :meth:`rows_to_host`: copy the row indices to
+        the state's device (booked h2d) and issue the (k, H) gather.
+        Returns the gathered device array without waiting for it."""
+        idx = self._put(np.asarray(rows, np.int32))
+        return h_dev[idx]
+
+    def rows_fetch(self, rows_dev) -> np.ndarray:
+        """Second half of :meth:`rows_to_host`: wait for the gather (and
+        whatever the device runs before it) and copy the rows to the host
+        (booked d2h)."""
+        out = np.array(rows_dev, np.float32)
         self.transfers.d2h(out.nbytes, state=True)
         return out
 
@@ -217,15 +229,11 @@ class Q15StreamStep:
         restore) — a (k, H) h2d instead of re-uploading the table."""
         values = np.ascontiguousarray(values, np.float32)
         self.transfers.h2d(values.nbytes, state=True)
-        return h_dev.at[np.asarray(rows)].set(values)
+        return h_dev.at[self._put(np.asarray(rows, np.int32))].set(values)
 
     def reset_device(self, h_dev, mask: np.ndarray):
         """Device-side :meth:`reset` — only the (S,) mask crosses h2d."""
-        mask = np.asarray(mask, bool)
-        self.transfers.h2d(mask.nbytes)
-        if self.device is not None:
-            mask = jax.device_put(mask, self.device)
-        return self._reset_resident(h_dev, mask)
+        return self._reset_resident(h_dev, self._put(np.asarray(mask, bool)))
 
     def concat_device(self, parts):
         """Device-side concat of per-shard h views (fused-tick fallback
@@ -240,13 +248,15 @@ class Q15StreamStep:
         for accelerators where donation pays — on CPU it measurably
         doesn't, see ``_build_jit_resident``).  Only x and the active
         mask cross h2d; h never touches the host."""
-        x = np.asarray(x, np.float32)
-        active = np.asarray(active, bool)
-        self.transfers.h2d(x.nbytes + active.nbytes)
-        if self.device is not None:
-            x = jax.device_put(x, self.device)
-            active = jax.device_put(active, self.device)
-        return self._resident_step(h_dev, x, active)
+        return self._resident_step(h_dev,
+                                   self._put(np.asarray(x, np.float32)),
+                                   self._put(np.asarray(active, bool)))
+
+    def _put(self, a: np.ndarray):
+        """One booked host->device copy of a (non-state) operand, to this
+        step's device; on the default device jax copies it at first use."""
+        self.transfers.h2d(a.nbytes)
+        return a if self.device is None else jax.device_put(a, self.device)
 
     def _build_jit_resident(self):
         # Deliberately NOT donate_argnums=0: buffer donation makes XLA's
@@ -303,30 +313,6 @@ class Q15StreamStep:
         return {"backend": self.backend,
                 "model_flops_per_stream_step": int(mm + 10 * H),
                 "hbm_bytes_per_stream_step": int(4 * (d + 2 * H))}
-
-    def roofline(self, stream_steps_per_sec: float) -> dict:
-        """:meth:`work_per_stream_step` at a measured stream-step rate,
-        against the peaks of the device this step dispatches to
-        (``launch/roofline.peaks``).  The exact backend and any CPU raise:
-        a host rate is not a device metric."""
-        from repro.launch import roofline as rl
-        kind = ("host NumPy" if self.backend == "exact"
-                else (self.device or jax.devices()[0]).device_kind)
-        pk = rl.peaks(kind)
-        work = self.work_per_stream_step()
-        rate = float(stream_steps_per_sec)
-        achieved = work["model_flops_per_stream_step"] * rate
-        return {
-            **work,
-            "device_kind": kind,
-            "stream_steps_per_sec": rate,
-            "achieved_gflops": achieved / 1e9,
-            "peak_fraction": achieved / pk["flops"],
-            "memory_bound_stream_steps_per_sec":
-                pk["hbm_bw"] / work["hbm_bytes_per_stream_step"],
-            "peak_flops": pk["flops"],
-            "hbm_bw_bytes_per_sec": pk["hbm_bw"],
-        }
 
     def device_constants(self) -> list:
         """The jax arrays a device backend dispatches against (weights,
@@ -407,7 +393,8 @@ class Q15StreamStep:
         dev, ledger, f = self.device, self.transfers, self._resident_step
 
         def run(h, x, active):
-            ledger.h2d(x.nbytes + active.nbytes)
+            ledger.h2d(x.nbytes)
+            ledger.h2d(active.nbytes)
             ledger.h2d(h.nbytes, state=True)
             if dev is not None:
                 h, x, active = (jax.device_put(h, dev),
@@ -430,7 +417,8 @@ class Q15StreamStep:
         m_p[:S, 0] = active
         # host-staged path: full padded h round-trip per tick (cf. the
         # zero-h-copy device-resident step_resident)
-        self.transfers.h2d(x_p.nbytes + m_p.nbytes)
+        self.transfers.h2d(x_p.nbytes)
+        self.transfers.h2d(m_p.nbytes)
         self.transfers.h2d(h_p.nbytes, state=True)
         if self.device is not None:
             args = (jax.device_put(x_p, self.device),

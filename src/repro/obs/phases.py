@@ -4,7 +4,8 @@ Span phases used to be free-form string literals scattered across the
 serving stack; a typo ("fleet.dispach") would silently intern a new
 phase, splitting its latency history and breaking downstream dashboards
 keyed on the documented names.  Every phase recorded through the
-:class:`repro.obs.trace.Tracer` API (``rec`` / ``span``) must be listed
+:class:`repro.obs.trace.Tracer` API (``open`` / ``open_count`` /
+``span``) must be listed
 here; the ``det-span-registry`` check in :mod:`repro.analysis.detlint`
 statically verifies every literal at every call site, and
 ``tests/test_obs.py`` asserts the registry covers the serving tree.
@@ -17,12 +18,13 @@ from __future__ import annotations
 #: Single-engine tick phases (serve/streaming.py).
 ENGINE_PHASES = (
     "engine.tick", "engine.gather", "engine.kernel", "engine.device_wait",
-    "engine.emit", "engine.finish",
+    "engine.emit", "engine.emit_pull", "engine.emit_wait",
+    "engine.emit_head", "engine.emit_reset", "engine.finish",
 )
 
 #: Fleet front-door tick phases (serve/fleet/engine.py).
 FLEET_PHASES = (
-    "fleet.tick", "fleet.begin", "fleet.dispatch", "fleet.dispatch_issue",
+    "fleet.feed", "fleet.tick", "fleet.begin", "fleet.dispatch", "fleet.dispatch_issue",
     "fleet.device_wait", "fleet.snapshot", "fleet.flush_spill",
     "fleet.deliver", "fleet.finish",
 )

@@ -8,7 +8,10 @@ moves across the host/device boundary — per-tick ``x``/mask staging
 ``state=True``), and emission/tap/snapshot row pulls.  The ledger is a
 handful of plain int adds, cheap enough to stay always-on (no
 Observability bundle required), and tests/benchmarks read it through
-``stats()["transfers"]``:
+``stats()["transfers"]``.  Besides bytes it counts crossings
+(``h2d_count`` / ``d2h_count``): on a host-bound tick each copy to the
+device costs a dispatch and each pull a sync, whatever its size.
+
 
 * a steady-state fused tick on the device-resident jit/pallas path books
   **zero** ``h_h2d_bytes``/``h_d2h_bytes`` (the regression gate in
@@ -23,30 +26,33 @@ invariant "no h crosses the boundary per steady tick" is the same.
 from __future__ import annotations
 
 #: Ledger/snapshot keys, in canonical order: total staged bytes each way,
-#: plus the hidden-state-only sub-accounts the zero-copy gate reads.
-TRANSFER_KEYS = ("h2d_bytes", "d2h_bytes", "h_h2d_bytes", "h_d2h_bytes")
+#: the hidden-state-only sub-accounts the zero-copy gate reads, and the
+#: number of crossings each way.
+TRANSFER_KEYS = ("h2d_bytes", "d2h_bytes", "h_h2d_bytes", "h_d2h_bytes",
+                 "h2d_count", "d2h_count")
 
 
 class TransferLedger:
-    """Monotonic host<->device byte counters (one per kernel instance)."""
+    """Monotonic host<->device counters (one per kernel instance)."""
 
     __slots__ = TRANSFER_KEYS
 
     def __init__(self) -> None:
-        self.h2d_bytes = 0
-        self.d2h_bytes = 0
-        self.h_h2d_bytes = 0
-        self.h_d2h_bytes = 0
+        for k in TRANSFER_KEYS:
+            setattr(self, k, 0)
 
     def h2d(self, nbytes: int, *, state: bool = False) -> None:
-        """Book a host->device transfer; ``state=True`` marks hidden-state
+        """Book one host->device copy; ``state=True`` marks hidden-state
         bytes (the zero-copy invariant's sub-account)."""
         self.h2d_bytes += nbytes
+        self.h2d_count += 1
         if state:
             self.h_h2d_bytes += nbytes
 
     def d2h(self, nbytes: int, *, state: bool = False) -> None:
+        """Book one device->host pull (a blocking copy)."""
         self.d2h_bytes += nbytes
+        self.d2h_count += 1
         if state:
             self.h_d2h_bytes += nbytes
 

@@ -171,9 +171,9 @@ class Engine:
         decode step over all resident sequences, release finished slots."""
         if not self._obs.enabled:
             return self.sched.tick()
-        t0 = self._tracer.t()
+        t0 = self._tracer.open("lm.tick")
         events = self.sched.tick()
-        dur_ns = self._tracer.rec("lm.tick", t0)
+        dur_ns = self._tracer.close(t0)
         if self._obs.metrics is not None:
             self._obs.metrics.histogram(
                 "lm.tick_us", "LM engine tick latency",
@@ -253,10 +253,10 @@ class Engine:
         batch = {"tokens": jnp.asarray(req.tokens[None, :])}
         if req.extra:
             batch.update({k: jnp.asarray(v) for k, v in req.extra.items()})
-        t0 = self._tracer.t()
+        t0 = self._tracer.open("lm.prefill")
         out, self.cache = self._prefill_fn(batch)(
             self.params, self.cache, batch, slot)
-        self._tracer.rec("lm.prefill", t0)
+        self._tracer.close(t0)
         logits = self._head_logits(out[:, -1:]) if self._quant_head \
             else out[:, -1, :]
         first = self._sample(logits)[0]
@@ -272,11 +272,11 @@ class Engine:
     def _advance(self, resident: np.ndarray) -> TickReport:
         need = resident & ~self._eos_done & (self._emitted < self._budget)
         if need.any():
-            t0 = self._tracer.t()
+            t0 = self._tracer.open("lm.decode")
             out, self.cache = self._decode(
                 self.params, self.cache, jnp.asarray(self._last),
                 jnp.asarray(need))
-            self._tracer.rec("lm.decode", t0)
+            self._tracer.close(t0)
             logits = self._head_logits(out) if self._quant_head \
                 else out[:, 0, :]
             nxt = self._sample(logits)                    # (S,) batched

@@ -254,7 +254,10 @@ class FleetEngine:
         lookups on the instrumented path)."""
         reg = self.obs.metrics
         self._m_tick = reg.histogram(
-            "fleet.tick_us", "wall time of one fleet tick", wallclock=True)
+            "fleet.tick_us",
+            "host time of one fleet tick; on the device-resident path its "
+            "device work is waited for in the next tick's fleet.device_wait",
+            wallclock=True)
         self._m_ticks = reg.counter("fleet.ticks", "fleet ticks")
         self._m_events = reg.counter(
             "fleet.events_emitted", "stream events delivered to the consumer")
@@ -419,18 +422,23 @@ class FleetEngine:
     def feed(self, stream_id: str, samples: np.ndarray) -> None:
         """Append samples to a stream, wherever it lives (shard-resident,
         shard-pending, or fleet-spilled)."""
-        shard = self._owner.get(stream_id)
-        if shard is not None and stream_id in self.shards[shard]._sessions:
-            coerced = self._check_samples(stream_id, samples)
-            self._journal_feed(stream_id, coerced)
-            self.shards[shard].feed(stream_id, coerced)
-            return
-        if stream_id in self._spilled:
-            coerced = self._check_samples(stream_id, samples)
-            self._journal_feed(stream_id, coerced)
-            self._spilled[stream_id].chunks.append(coerced)
-            return
-        raise KeyError(f"stream {stream_id!r} is not attached")
+        tr = self._tracer
+        tok = tr.open_count("fleet.feed")
+        try:
+            shard = self._owner.get(stream_id)
+            if shard is not None and stream_id in self.shards[shard]._sessions:
+                coerced = self._check_samples(stream_id, samples)
+                self._journal_feed(stream_id, coerced)
+                self.shards[shard].feed(stream_id, coerced)
+                return
+            if stream_id in self._spilled:
+                coerced = self._check_samples(stream_id, samples)
+                self._journal_feed(stream_id, coerced)
+                self._spilled[stream_id].chunks.append(coerced)
+                return
+            raise KeyError(f"stream {stream_id!r} is not attached")
+        finally:
+            tr.close(tok)
 
     def detach(self, stream_id: str) -> StreamEvent | None:
         """Terminate a stream (partial-window final event if it consumed
@@ -474,17 +482,17 @@ class FleetEngine:
         tr = self._tracer
         self._ticks += 1
         tr.set_tick(self._ticks)
-        t_tick = tr.t()
+        t_tick = tr.open("fleet.tick")
         self._fire("pre_tick")
         se = self.config.snapshot_every
         if se is not None and self._ticks % se == 0:
-            t0 = tr.t()
+            t0 = tr.open("fleet.snapshot")
             self.snapshot_now()
-            tr.rec("fleet.snapshot", t0)
+            tr.close(t0)
         if self._spilled:
-            t0 = tr.t()
+            t0 = tr.open("fleet.flush_spill")
             self._flush_spill()
-            tr.rec("fleet.flush_spill", t0)
+            tr.close(t0)
         live = self.n_active + self.n_pending
         if len(self._owner) > 2 * live + 1024:
             self._compact_owners()       # bound stale finished-id entries
@@ -500,11 +508,11 @@ class FleetEngine:
                 events.extend(out)
         else:
             events = self._step_fused()
-        t0 = tr.t()
+        t0 = tr.open("fleet.deliver")
         self._deliver(events)
-        tr.rec("fleet.deliver", t0)
+        tr.close(t0)
         self._fire("post_emit")
-        dur_ns = tr.rec("fleet.tick", t_tick)
+        dur_ns = tr.close(t_tick)
         if self.obs.metrics is not None:
             self._tick_metrics(dur_ns, events)
         return events
@@ -519,20 +527,20 @@ class FleetEngine:
         # instead of copying, so overwriting them while a dispatch still
         # reads them corrupts the in-flight tick.
         if self._inflight:
-            t0 = tr.t()
+            t0 = tr.open("fleet.device_wait")
             for arr in self._inflight:
                 arr.block_until_ready()
             self._inflight.clear()
-            tr.rec("fleet.device_wait", t0)
+            tr.close(t0)
         # phase 1: every shard runs admission + ring gather (no kernel)
-        t0 = tr.t()
+        t0 = tr.open("fleet.begin")
         begun: list[tuple] = []
         for shard in self.shards:
             resident = shard._sched.tick_begin()
             handle = (shard._advance_begin(resident)
                       if resident is not None else None)
             begun.append((resident, handle))
-        tr.rec("fleet.begin", t0)
+        tr.close(t0)
         # a shard crashed between the tick's two halves never reaches the
         # kernel: its gathered handle points at the dead engine's arrays
         for i in self._fire("mid_dispatch"):
@@ -542,12 +550,12 @@ class FleetEngine:
         # any is waited on — co-located shards batch, distinct devices
         # compute concurrently.
         h_out: dict[int, np.ndarray] = {}
-        t0 = tr.t()
+        t0 = tr.open("fleet.dispatch")
         for g in self._group_list:
             self._dispatch_group(g, begun, h_out)
-        tr.rec("fleet.dispatch", t0)
+        tr.close(t0)
         # phase 3: per-shard bookkeeping + scheduler release accounting
-        t0 = tr.t()
+        t0 = tr.open("fleet.finish")
         events: list[StreamEvent] = []
         rec = self.obs.recorder
         for i, (resident, handle) in enumerate(begun):
@@ -562,7 +570,7 @@ class FleetEngine:
             if rec is not None and out:
                 self._note_shard_events(i, out)
             events.extend(out)
-        tr.rec("fleet.finish", t0)
+        tr.close(t0)
         return events
 
     def _dispatch_group(self, g: _DeviceGroup, begun: list,
@@ -601,12 +609,12 @@ class FleetEngine:
             adopted = (g.h_big is not None and
                        all((p := self.shards[i]._h_pending) is not None
                            and p[0] is g.h_big for i in idxs))
-            t0 = tr.t()
+            t0 = tr.open("fleet.dispatch_issue", idxs[0])
             h_cat = (g.h_big if adopted
                      else g.kernel.concat_device(
                          [self.shards[i]._resolve_h() for i in idxs]))
             h_new = g.kernel.step_resident(h_cat, g.x_big, av)
-            tr.rec("fleet.dispatch_issue", t0, idxs[0])
+            tr.close(t0)
             self._inflight.append(h_new)
             g.h_big = h_new
             # per-shard views are LAZY: a real device slice here costs

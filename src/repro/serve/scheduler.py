@@ -220,11 +220,11 @@ class SlotScheduler:
         if report.advanced:
             self._ticks += 1
         if len(report.finished):
-            t0 = self.tracer.t()
+            t0 = self.tracer.open("sched.release", self.shard)
             for slot in report.finished:
                 self._release(int(slot), reason="finished")
                 self._completed += 1
-            self.tracer.rec("sched.release", t0, self.shard)
+            self.tracer.close(t0)
         return report.events
 
     def has_work(self) -> bool:
@@ -273,11 +273,11 @@ class SlotScheduler:
             return
         if self.admit_policy == "all_free" and self.resident.any():
             return
-        t0 = self.tracer.t()
+        t0 = self.tracer.open("sched.admit", self.shard)
         while self._free and self._pending:
             rid = self._pending.popleft()
             self._place(rid, self._free.pop())
-        self.tracer.rec("sched.admit", t0, self.shard)
+        self.tracer.close(t0)
 
     def _place(self, request_id: str, slot: int) -> None:
         payload = self._payloads.pop(request_id)

@@ -468,9 +468,9 @@ class StreamingEngine:
             return self._sched.tick()
         tr = self._tracer
         self._last_advanced = 0
-        t0 = tr.t()
+        t0 = tr.open("engine.tick", self._obs_shard)
         events = self._sched.tick()
-        dur_ns = tr.rec("engine.tick", t0, self._obs_shard)
+        dur_ns = tr.close(t0)
         if self._obs.metrics is not None:
             self._tick_metrics(dur_ns, self._last_advanced)
         return events
@@ -611,7 +611,7 @@ class StreamingEngine:
         avail, rows = handle
         mon = self._numerics()
         tr = self._tracer
-        t0 = tr.t()
+        t0 = tr.open("engine.kernel", self._obs_shard)
         if self._device_resident:
             # async dispatch; self._h is consumed by the step.  The
             # output is adopted immediately — emission/tap row pulls
@@ -637,7 +637,7 @@ class StreamingEngine:
                 self.kernel.numeric_events = None
                 self._flush_numeric_events(mon)
                 self._num_tallied = True
-        tr.rec("engine.kernel", t0, self._obs_shard)
+        tr.close(t0)
         return self._advance_finish(handle, h_new)
 
     def _advance_begin(self, resident: np.ndarray):
@@ -652,15 +652,15 @@ class StreamingEngine:
             # staging buffer it aliased at device_put time — sync before
             # the gather below overwrites it (the double-buffer boundary:
             # everything since the last dispatch overlapped device compute)
-            t0 = self._tracer.t()
+            t0 = self._tracer.open("engine.device_wait", self._obs_shard)
             self._h.block_until_ready()
-            self._tracer.rec("engine.device_wait", t0, self._obs_shard)
+            self._tracer.close(t0)
             self._h_inflight = False
         avail = resident & (self._tail > self._head)
         rows = np.nonzero(avail)[0]
         if rows.size == 0:
             return None
-        t0 = self._tracer.t()
+        t0 = self._tracer.open("engine.gather", self._obs_shard)
         # gather one sample per advancing slot from the ring (vectorized)
         x = self._x
         full = rows.size == x.shape[0]
@@ -675,7 +675,7 @@ class StreamingEngine:
         else:                          # streams drifted apart: 2-d gather
             x[:] = 0.0
             x[rows] = self._ring[heads % self._cap, rows]
-        self._tracer.rec("engine.gather", t0, self._obs_shard)
+        self._tracer.close(t0)
         mon = self._numerics()
         self._num_tallied = False
         if mon is not None:
@@ -700,7 +700,8 @@ class StreamingEngine:
         bookkeeping — cursors, counters, trajectory taps, window/final
         emission, tumbling-window resets."""
         avail, rows = handle
-        t_fin = self._tracer.t()
+        tr, shard = self._tracer, self._obs_shard
+        t_fin = tr.open("engine.finish", shard)
         self._last_advanced = int(rows.size)
         mon = self._numerics()
         if mon is not None and not self._num_tallied \
@@ -747,7 +748,11 @@ class StreamingEngine:
         events: list[StreamEvent] = []
         finished_rows: list[int] = []
         if emit_rows.size:               # rare tick: something emits
-            t_emit = self._tracer.t()
+            # engine.emit's four children tile it: the row pull's issue,
+            # its blocking completion (which holds the wait for this
+            # tick's step), the head with the events, the window resets
+            t_emit = tr.open("engine.emit", shard)
+            t0 = tr.open("engine.emit_pull", shard)
             # replay cursor: events the consumer already saw before a
             # crash are swallowed; window-reset/finish bookkeeping below
             # still uses the full emit set, so the recovered state
@@ -755,8 +760,13 @@ class StreamingEngine:
             deliver = emit_rows[
                 self._steps[emit_rows] > self._suppress[emit_rows]]
             self._replay_suppressed += int(emit_rows.size - deliver.size)
+            pulled = self._h_rows_issue(deliver) if deliver.size else None
+            tr.close(t0)
             if deliver.size:
-                h_emit = self._h_rows(deliver)
+                t0 = tr.open("engine.emit_wait", shard)
+                h_emit = self._h_rows_fetch(pulled)
+                tr.close(t0)
+                t0 = tr.open("engine.emit_head", shard)
                 logits = self.kernel.head_logits(h_emit)
                 mon = self._numerics()
                 if mon is not None:
@@ -774,6 +784,8 @@ class StreamingEngine:
                             kind, int(self._wstep[slot]), logits[i]))
                 if self._obs.metrics is not None:
                     self._emit_metrics(deliver)
+                tr.close(t0)
+            t0 = tr.open("engine.emit_reset", shard)
             finished_rows = np.nonzero(finished)[0].tolist()
             if np.any(at_window):
                 self._wstep[at_window] = 0
@@ -784,8 +796,9 @@ class StreamingEngine:
                         self._h_pending = None
                     else:
                         self._h = self.kernel.reset(self._h, at_window)
-            self._tracer.rec("engine.emit", t_emit, self._obs_shard)
-        self._tracer.rec("engine.finish", t_fin, self._obs_shard)
+            tr.close(t0)
+            tr.close(t_emit)
+        tr.close(t_fin)
         return TickReport(events=events, finished=finished_rows,
                           advanced=int(rows.size))
 
@@ -856,9 +869,21 @@ class StreamingEngine:
         a plain fancy-index copy on the host path, a booked (k, H) d2h
         pull on the device-resident path (only the rows the host actually
         needs — emission, taps — ever cross the boundary)."""
+        return self._h_rows_fetch(self._h_rows_issue(rows))
+
+    def _h_rows_issue(self, rows):
+        """First half of :meth:`_h_rows`: the issued device gather on the
+        device-resident path, the host copy itself on the host path."""
         if self._device_resident:
-            return self.kernel.rows_to_host(self._resolve_h(), rows)
+            return self.kernel.rows_issue(self._resolve_h(), rows)
         return self._h[rows]
+
+    def _h_rows_fetch(self, pulled) -> np.ndarray:
+        """Second half of :meth:`_h_rows`: wait for an issued gather and
+        copy it to the host (the host path's rows pass through)."""
+        if self._device_resident:
+            return self.kernel.rows_fetch(pulled)
+        return pulled
 
     def _h_row(self, slot: int) -> np.ndarray:
         """One hidden-state row as a fresh host copy (snapshot path).

@@ -303,16 +303,17 @@ def test_pallas_step_matches_exact(low_rank, S):
 
 
 def test_roofline_report(qp):
-    """Work counts come from shapes anywhere; achieved-vs-peak needs a
-    device with published peaks, so a CPU or the host backend raises."""
+    """Work counts come from shapes anywhere; peaks exist only for a
+    device with published figures, so a CPU or the host backend raises."""
+    from repro.launch import roofline as rl
     k = Q15StreamStep(qp, backend="pallas")
     w = k.work_per_stream_step()
     assert w == {"backend": "pallas", "model_flops_per_stream_step": 748,
                  "hbm_bytes_per_stream_step": 140}
     with pytest.raises(ValueError, match="'cpu'"):
-        k.roofline(1e6)
+        rl.peaks(jax.devices()[0].device_kind)
     with pytest.raises(ValueError, match="host NumPy"):
-        Q15StreamStep(qp).roofline(1e6)
+        rl.peaks("host NumPy")
 
 
 def test_peaks_table_keyed_by_device_kind():

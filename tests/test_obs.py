@@ -18,7 +18,10 @@ Contracts locked in here:
 * **O(shards) stats** — ``FleetEngine.stats()`` never walks per-stream
   containers (regression test poisons them).
 """
+import glob
 import json
+import os
+import tempfile
 import tracemalloc
 
 import jax
@@ -28,10 +31,10 @@ import pytest
 from faultharness import make_streams, run_crash_schedule
 from repro.core import fastgrnn as fg
 from repro.core.quantization import QuantConfig, quantize_params
-from repro.obs import (BUCKET_EDGES_US, NULL_OBS, NULL_TRACER, FlightRecorder,
-                       Histogram, MetricsRegistry, Observability, Tracer,
-                       check_conservation, merge_histogram_counts,
-                       validate_snapshot)
+from repro.obs import (BUCKET_EDGES_US, NULL_OBS, NULL_TRACER, PHASES,
+                       FlightRecorder, Histogram, MetricsRegistry,
+                       Observability, Tracer, check_conservation,
+                       merge_histogram_counts, validate_snapshot)
 from repro.serve.fleet import FleetConfig, FleetEngine, crash_matrix
 from repro.serve.streaming import StreamingConfig, StreamingEngine
 
@@ -56,10 +59,10 @@ def test_tracer_records_spans_and_phase_stats():
     tr = Tracer(capacity=16)
     tr.set_tick(3)
     for _ in range(5):
-        t0 = tr.t()
-        tr.rec("phase.a", t0, shard=1)
-    t0 = tr.t()
-    tr.rec("phase.b", t0)
+        t0 = tr.open("phase.a", shard=1)
+        tr.close(t0)
+    t0 = tr.open("phase.b")
+    tr.close(t0)
     st = tr.phase_stats()
     assert set(st) == {"phase.a", "phase.b"}
     assert st["phase.a"]["count"] == 5
@@ -76,7 +79,7 @@ def test_tracer_records_spans_and_phase_stats():
 def test_tracer_ring_wraps_without_growth():
     tr = Tracer(capacity=8)
     for i in range(50):
-        tr.rec("p", tr.t())
+        tr.close(tr.open("p"))
     assert len(tr.flight()) == 8                      # bounded
     assert [r["seq"] for r in tr.flight()] == list(range(42, 50))
     assert tr.phase_stats()["p"]["count"] == 50       # monotonic total
@@ -84,7 +87,7 @@ def test_tracer_ring_wraps_without_growth():
 
 def test_tracer_deterministic_flight_strips_wallclock():
     tr = Tracer(capacity=8)
-    tr.rec("p", tr.t(), shard=2)
+    tr.close(tr.open("p", shard=2))
     det = tr.flight(deterministic=True)[0]
     assert set(det) == {"seq", "tick", "phase", "shard"}
     full = tr.flight()[0]
@@ -101,21 +104,53 @@ def test_tracer_span_context_manager():
     assert tr.totals_s()["ctx.phase"] > 0
 
 
-def test_null_tracer_is_allocation_free():
+def test_tracer_close_drops_unclosed_children_and_counts_quietly():
+    tr = Tracer(capacity=16)
+    outer = tr.open("phase.outer")
+    tr.open("phase.left_open", shard=1)          # never closed
+    tr.close(outer)
+    again = tr.open("phase.outer")
+    assert again == outer                        # the stack unwound
+    tr.close(again)
+    for _ in range(3):
+        tr.close(tr.open_count("phase.quiet"))
+    st = tr.phase_stats()
+    assert st["phase.quiet"]["count"] == 3
+    assert st["phase.outer"]["count"] == 2
+    assert "phase.left_open" not in {r["phase"] for r in tr.flight()}
+    assert [r["phase"] for r in tr.flight()] == ["phase.outer"] * 2
+
+
+def _null_open_close(tr):
+    t0 = tr.open("engine.tick", 3)
+    tr.close(t0)
+
+
+def _null_open_count(tr):
+    t0 = tr.open_count("fleet.feed")
+    tr.close(t0)
+
+
+def _null_span_tick(tr):
+    with tr.span("engine.tick", 3):
+        tr.set_tick(7)
+
+
+@pytest.mark.parametrize("call", [_null_open_close, _null_open_count,
+                                  _null_span_tick],
+                         ids=["open-close", "open_count", "span"])
+def test_null_tracer_is_allocation_free(call):
     """The disabled path must not allocate: this is what keeps the
-    bit-exact fast path untouched when obs is off."""
+    bit-exact fast path untouched when obs is off.  One case per span
+    form of the API."""
     tr = NULL_TRACER
     # warm up (interned small ints, method caches)
     for _ in range(10):
-        tr.rec("x", tr.t(), 0)
-        tr.set_tick(1)
-        with tr.span("x"):
-            pass
+        call(tr)
+
     def burst(n):
         for _ in range(n):
-            t0 = tr.t()
-            tr.rec("engine.tick", t0, 3)
-            tr.set_tick(7)
+            call(tr)
 
     def leaked_by(n):
         before, _ = tracemalloc.get_traced_memory()
@@ -372,8 +407,33 @@ def test_debug_mode_stats_asserts_conservation(qp, input_dim,
 # Engine integration: spans, metrics, deadline + warm-up accounting
 # ---------------------------------------------------------------------------
 
-def test_fleet_traced_run_bit_identical_to_untraced(qp, input_dim):
-    """Full instrumentation must not perturb a single output bit."""
+def _profiled(fn):
+    """``fn()`` under a profiler session: its result and the host planes'
+    events as (name, start_ns, end_ns, thread line)."""
+    from jax.profiler import ProfileData, ProfileOptions
+    opts = ProfileOptions()
+    opts.python_tracer_level = 0
+    with tempfile.TemporaryDirectory() as d:
+        jax.profiler.start_trace(d, profiler_options=opts)
+        try:
+            out = fn()
+        finally:
+            jax.profiler.stop_trace()
+        path, = glob.glob(os.path.join(d, "**", "*.xplane.pb"),
+                          recursive=True)
+        events = [(e.name, e.start_ns, e.start_ns + e.duration_ns, ln.name)
+                  for plane in ProfileData.from_file(path).planes
+                  if plane.name.startswith("/host:")
+                  for ln in plane.lines for e in ln.events]
+    return out, events
+
+
+@pytest.mark.parametrize("profiling", [False, True],
+                         ids=["no-profiler", "profiler-on"])
+def test_fleet_traced_run_bit_identical_to_untraced(qp, input_dim,
+                                                    profiling):
+    """Full instrumentation must not perturb a single output bit, also
+    while a profiler session records the spans."""
     streams = make_streams(12, 150, input_dim, seed=3)
 
     def run(obs):
@@ -384,7 +444,104 @@ def test_fleet_traced_run_bit_identical_to_untraced(qp, input_dim):
         from faultharness import collect_log
         return collect_log(fleet.drain())
 
-    assert run(NULL_OBS) == run(Observability.full(debug=True))
+    traced = lambda: run(Observability.full(debug=True))
+    got = _profiled(traced)[0] if profiling else traced()
+    assert run(NULL_OBS) == got
+
+
+def _resident_fleet(qp, obs=None):
+    """Two shards on one device, resident h, 8-sample windows."""
+    return FleetEngine(qp, FleetConfig(
+        shards=2, placement="host", stream=StreamingConfig(
+            max_slots=4, window=8, backend="jit", device_resident=True)),
+        obs=obs)
+
+
+def test_fleet_spans_nest_in_the_profiler_trace(qp, input_dim):
+    """Under a profiler session the fleet's spans are TraceMe events on
+    the host plane, nested by interval as the code nests them."""
+    fleet = _resident_fleet(qp, Observability(tracer=Tracer()))
+    for sid, w in make_streams(8, 24, input_dim).items():
+        fleet.attach(sid, w)
+    for _ in range(9):                  # compile, admit, one emission
+        fleet.step()
+
+    def emitting_ticks():
+        for _ in range(8):              # the next window ends in here
+            fleet.step()
+
+    _, events = _profiled(emitting_ticks)
+    by = lambda name: [e for e in events if e[0] == name]
+    inside = lambda a, b: b[1] <= a[1] and a[2] <= b[2] and a[3] == b[3]
+    chain = ("engine.emit_wait", "engine.emit", "fleet.finish", "fleet.tick")
+    waits = by(chain[0])
+    assert waits, sorted({e[0] for e in events})
+    for wait in waits:
+        inner = wait
+        for parent in chain[1:]:
+            outers = [e for e in by(parent) if inside(inner, e)]
+            assert len(outers) == 1, (inner, parent)
+            inner = outers[0]
+
+
+def test_null_tracer_writes_no_span_into_the_profiler_trace(qp, input_dim):
+    fleet = _resident_fleet(qp)
+    for sid, w in make_streams(8, 24, input_dim).items():
+        fleet.attach(sid, w)
+    fleet.step()
+    _, events = _profiled(lambda: [fleet.step() for _ in range(10)])
+    assert events                       # the session recorded something
+    assert not {e[0] for e in events} & PHASES
+
+
+def test_fleet_feed_counted_outside_the_flight_ring(qp, input_dim):
+    """``fleet.feed`` counts every call in the phase statistics and
+    stays out of the flight ring, whose deterministic dump is byte-stable
+    across identical runs."""
+    streams = make_streams(6, 40, input_dim, seed=5)
+
+    def run():
+        obs = Observability.full()
+        fleet = FleetEngine(qp, FleetConfig(
+            shards=2, stream=StreamingConfig(max_slots=8, window=16)),
+            obs=obs)
+        for sid in streams:
+            fleet.attach(sid)
+        feeds = 0
+        for k in range(0, 40, 10):
+            for sid, w in streams.items():
+                fleet.feed(sid, w[k:k + 10])
+                feeds += 1
+            for _ in range(10):
+                fleet.step()
+        return obs, feeds
+
+    (obs, feeds), (obs2, _) = run(), run()
+    assert obs.tracer.phase_stats()["fleet.feed"]["count"] == feeds == 24
+    flight = obs.tracer.flight(deterministic=True)
+    assert "fleet.feed" not in {r["phase"] for r in flight}
+    assert json.dumps(flight) == json.dumps(
+        obs2.tracer.flight(deterministic=True))
+    assert (obs.recorder.dumps(deterministic=True)
+            == obs2.recorder.dumps(deterministic=True))
+
+
+def test_resident_tick_books_its_crossings(qp, input_dim):
+    """A steady resident tick copies x and the active mask to the device
+    and pulls nothing; an emitting tick adds, per emitting shard, the
+    row indices and the window-reset mask up and the rows down."""
+    fleet = _resident_fleet(qp)
+    for sid, w in make_streams(8, 24, input_dim).items():
+        fleet.attach(sid, w)
+    fleet.step()                         # admission
+    crossings = []
+    for _ in range(7):                   # ticks 2-8: the window ends at 8
+        before = fleet.stats()["transfers"]
+        fleet.step()
+        after = fleet.stats()["transfers"]
+        crossings.append((after["h2d_count"] - before["h2d_count"],
+                          after["d2h_count"] - before["d2h_count"]))
+    assert crossings == [(2, 0)] * 6 + [(2 + 2 * 2, 2)]
 
 
 def test_fleet_tick_phases_traced(qp, input_dim):
@@ -405,6 +562,12 @@ def test_fleet_tick_phases_traced(qp, input_dim):
         assert phase in st, f"missing phase {phase}: have {sorted(st)}"
     # the tick envelope dominates its parts
     assert st["fleet.tick"]["total_us"] >= st["fleet.dispatch"]["total_us"]
+    # engine.emit's four children tile it
+    kids = ("engine.emit_pull", "engine.emit_wait", "engine.emit_head",
+            "engine.emit_reset")
+    assert all(st[k]["count"] == st["engine.emit"]["count"] for k in kids)
+    assert sum(st[k]["total_us"] for k in kids) <= \
+        st["engine.emit"]["total_us"]
     # spans are tagged with real shard indices
     shards = {r["shard"] for r in obs.tracer.flight()
               if r["phase"] == "engine.gather"}
@@ -675,7 +838,8 @@ def test_every_serving_span_phase_is_registered():
                 for node in ast.walk(tree):
                     if (isinstance(node, ast.Call)
                             and isinstance(node.func, ast.Attribute)
-                            and node.func.attr in ("rec", "span")
+                            and node.func.attr in ("open", "open_count",
+                                                   "span")
                             and node.args
                             and isinstance(node.args[0], ast.Constant)
                             and isinstance(node.args[0].value, str)):

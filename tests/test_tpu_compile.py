@@ -65,6 +65,7 @@ def test_fleet_step_kernel_compiles(art, one_chip, compiled_path):
     text = jax.jit(K.fastgrnn_step_call(sw, S_FLEET)).lower(
         *args).compile().as_text()
     assert "tpu_custom_call" in text
+    assert "%q15_step" in text          # the op's stable name in a trace
 
 
 def test_jit_resident_step_compiles(art, one_chip, compiled_path):
@@ -85,6 +86,7 @@ def test_window_kernel_compiles(one_chip, compiled_path):
         f32(2, LANES), f32(2, LANES), f32(T, B, LANES), f32(LANES, LANES),
         f32(LANES, LANES), f32(4, LANES)).compile().as_text()
     assert "tpu_custom_call" in text
+    assert "%q15_window" in text
 
 
 def test_q15_matmul_compiles_at_lm_head_width(one_chip, compiled_path):
