@@ -134,6 +134,10 @@ class Q15StreamStep:
         # device-side reset: jitted masked zero (no host h round-trip)
         self._reset_resident = jax.jit(
             lambda h, m: jnp.where(m[:, None], jnp.float32(0.0), h))
+        # device-side row gather: one cached executable per row count,
+        # where op-by-op fancy indexing dispatches its index arithmetic
+        # as separate ops on every call
+        self._take_rows = jax.jit(lambda h, idx: h[idx])
 
     # -- state management ---------------------------------------------------
     @property
@@ -213,8 +217,7 @@ class Q15StreamStep:
         """First half of :meth:`rows_to_host`: copy the row indices to
         the state's device (booked h2d) and issue the (k, H) gather.
         Returns the gathered device array without waiting for it."""
-        idx = self._put(np.asarray(rows, np.int32))
-        return h_dev[idx]
+        return self._take_rows(h_dev, self._put(np.asarray(rows, np.int32)))
 
     def rows_fetch(self, rows_dev) -> np.ndarray:
         """Second half of :meth:`rows_to_host`: wait for the gather (and

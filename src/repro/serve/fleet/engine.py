@@ -30,10 +30,12 @@ Design
   `StreamingEngine._advance_begin`), then the fleet concatenates every
   co-located shard's (h, x, active) and makes ONE batched
   ``Q15StreamStep`` dispatch per device group, then each shard finishes
-  its own bookkeeping.  The per-row math is row-independent, so fusion
-  preserves the bit-exactness contract while amortizing per-dispatch
-  overhead across shards — the measured source of near-linear shard
-  scaling on CPU (``benchmarks/fleet_bench.py``).
+  its own bookkeeping; on the device-resident path a group's emission is
+  one row gather, one window reset and one pull over its fused output.
+  The per-row math is row-independent, so fusion preserves the
+  bit-exactness contract while amortizing per-dispatch overhead across
+  shards — the measured source of near-linear shard scaling on CPU
+  (``benchmarks/fleet_bench.py``).
 * **Placement** — shards are assigned distinct jax devices when the
   process has them (``fleet/placement.py``; CPU runners fake them via
   ``--xla_force_host_platform_device_count``) and fall back to
@@ -116,9 +118,10 @@ class _DeviceGroup:
     """Fused-dispatch state of one device group (the co-located shards
     whose ticks batch into ONE kernel call).  Each shard's ``_x`` is a
     view of ``x_big``, so phase-1 ring gathers write the fused x operand
-    in place; ``h_big`` is last tick's fused output with per-shard views
-    handed back, adopted as this tick's h operand whenever every shard
-    still holds its view (steady state: zero copies besides the kernel's
+    in place; ``h_big`` is last tick's fused output (after its window
+    resets, on the device-resident path) with per-shard views handed
+    back, adopted as this tick's h operand whenever every shard still
+    holds its view (steady state: zero copies besides the kernel's
     own output — and on the device-resident path ``h_big`` is a jax
     device array consumed in place by the step, so steady-state ticks
     never move a single h byte across the host/device boundary)."""
@@ -556,17 +559,16 @@ class FleetEngine:
         tr.close(t0)
         # phase 3: per-shard bookkeeping + scheduler release accounting
         t0 = tr.open("fleet.finish")
+        reports = self._finish_shards(begun, h_out)
         events: list[StreamEvent] = []
         rec = self.obs.recorder
-        for i, (resident, handle) in enumerate(begun):
+        for i, (resident, _) in enumerate(begun):
             self._advanced_per_shard[i] = 0
             if resident is None:
                 continue
-            shard = self.shards[i]
-            report = (shard._advance_finish(handle, h_out[i])
-                      if handle is not None else TickReport())
+            report = reports.get(i) or TickReport()
             self._advanced_per_shard[i] = report.advanced
-            out = shard._sched.tick_finish(report)
+            out = self.shards[i]._sched.tick_finish(report)
             if rec is not None and out:
                 self._note_shard_events(i, out)
             events.extend(out)
@@ -604,8 +606,9 @@ class FleetEngine:
                 av[off[j]:off[j + 1]] = begun[i][1][0]
         if self._device_resident:
             # adoption token: every shard's lazy view spec still points
-            # at this group's last fused output (a shard that rebound
-            # its h — reset, admission, migration restore — cleared it)
+            # at this group's fused state (a shard that rebound its h —
+            # admission, migration restore, a reset on its own emission
+            # path — cleared it)
             adopted = (g.h_big is not None and
                        all((p := self.shards[i]._h_pending) is not None
                            and p[0] is g.h_big for i in idxs))
@@ -616,7 +619,6 @@ class FleetEngine:
             h_new = g.kernel.step_resident(h_cat, g.x_big, av)
             tr.close(t0)
             self._inflight.append(h_new)
-            g.h_big = h_new
             # per-shard views are LAZY: a real device slice here costs
             # one dispatch per shard per tick (~35% of a steady-state
             # 1024-slot tick); instead each shard gets a provenance spec
@@ -624,14 +626,9 @@ class FleetEngine:
             # (emission, taps, snapshots, resets).  Idle shards' rows
             # passed through the kernel masked (bit-preserved), so the
             # same spec keeps their state current with no host traffic.
-            whole = h_new if len(idxs) == 1 else None
-            for j, i in enumerate(idxs):
-                sh = self.shards[i]
-                sh._h = whole
-                sh._h_pending = (h_new, off[j], off[j + 1])
-                g.h_views[j] = None
-                if i in live:
-                    h_out[i] = None
+            self._hand_out(g, h_new, idxs)
+            for i in live:
+                h_out[i] = None
             return
         adopted = (g.h_big is not None and
                    all(self.shards[i]._h is g.h_views[j]
@@ -645,6 +642,106 @@ class FleetEngine:
             g.h_views[j] = view
             if i in live:
                 h_out[i] = view
+
+    def _hand_out(self, g: _DeviceGroup, h_new, idxs) -> None:
+        """Make the device array ``h_new`` the group's fused state and hand
+        each of the shards ``idxs`` a lazy view spec into it."""
+        whole = h_new if len(g.idxs) == 1 else None
+        for j, i in enumerate(g.idxs):
+            if i in idxs:
+                sh = self.shards[i]
+                sh._h = whole
+                sh._h_pending = (h_new, g.offsets[j], g.offsets[j + 1])
+                g.h_views[j] = None
+        g.h_big = h_new
+
+    def _finish_shards(self, begun: list, h_out: dict) -> dict:
+        """Phase 3's engine half: every advanced shard's
+        :class:`TickReport`, by shard index.  A shard of a device-resident
+        group that still holds its view of the group's fused output plans
+        its emission on the host, and :meth:`_emit_groups` then pulls and
+        resets the whole group's rows at once.  Any other shard (the
+        host-staged path, or a shard whose h was rebound since the
+        dispatch) finishes on its own."""
+        tr = self._tracer
+        reports: dict[int, TickReport] = {}
+        plans: dict[int, Any] = {}
+        for i, (_, handle) in enumerate(begun):
+            if handle is None:
+                continue
+            sh = self.shards[i]
+            p = sh._h_pending
+            if (self._device_resident and p is not None
+                    and p[0] is self._group_of[i].h_big):
+                t0 = tr.open("engine.finish", i)
+                plans[i] = sh._finish_plan(handle, None)
+                tr.close(t0)
+            else:
+                reports[i] = sh._advance_finish(handle, h_out[i])
+        reports.update(self._emit_groups(plans))
+        return reports
+
+    def _emit_groups(self, plans: dict) -> dict:
+        """Emission over each device group's fused output: one row gather
+        for every shard's delivered rows, one window reset over the whole
+        group, one blocking pull, then each shard's head and events from
+        its share of the rows.  Every group's gather and reset is issued
+        before any group's rows are waited on.  The reset's output becomes
+        the group's fused state, so the next tick adopts it with no
+        concatenate.  The four ``engine.emit_*`` spans open once per
+        group, inside one ``engine.emit``."""
+        tr = self._tracer
+        reports = {i: TickReport(advanced=p.advanced)
+                   for i, p in plans.items() if not p.rows.size}
+        work = []
+        for g in self._group_list:
+            mine = [(j, i, plans[i]) for j, i in enumerate(g.idxs)
+                    if i in plans and plans[i].rows.size]
+            if mine:
+                work.append((g, mine))
+        if not work:
+            return reports
+        t_emit = tr.open("engine.emit", work[0][0].idxs[0])
+        pulls = []
+        for g, mine in work:
+            tag, off = g.idxs[0], g.offsets
+            t0 = tr.open("engine.emit_pull", tag)
+            idx = np.concatenate([p.deliver + off[j] for j, _, p in mine])
+            pulls.append(g.kernel.rows_issue(g.h_big, idx)
+                         if idx.size else None)
+            tr.close(t0)
+            t0 = tr.open("engine.emit_reset", tag)
+            if self.config.stream.reset_on_emit and any(
+                    p.at_window[p.rows].any() for _, _, p in mine):
+                # a fresh mask every tick: the put may alias host memory
+                # that the queued reset still reads
+                mask = np.zeros(int(off[-1]), bool)
+                for j, _, p in mine:
+                    mask[off[j]:off[j + 1]] = p.at_window
+                held = [i for j, i in enumerate(g.idxs)
+                        if (h := self.shards[i]._h_pending) is not None
+                        and h[0] is g.h_big]
+                self._hand_out(g, g.kernel.reset_device(g.h_big, mask), held)
+            tr.close(t0)
+        for (g, mine), pulled in zip(work, pulls):
+            tag = g.idxs[0]
+            if pulled is not None:
+                t0 = tr.open("engine.emit_wait", tag)
+                h_emit = g.kernel.rows_fetch(pulled)
+                tr.close(t0)
+                ends = np.cumsum([p.deliver.size for _, _, p in mine])
+                parts = np.split(h_emit, ends[:-1])
+            t0 = tr.open("engine.emit_head", tag)
+            for k, (_, i, p) in enumerate(mine):
+                sh = self.shards[i]
+                events = (sh._emit_events(p, parts[k]) if p.deliver.size
+                          else [])
+                sh._wstep[p.at_window] = 0
+                reports[i] = TickReport(events=events, finished=p.finished,
+                                        advanced=p.advanced)
+            tr.close(t0)
+        tr.close(t_emit)
+        return reports
 
     def drain(self) -> list[StreamEvent]:
         """Tick until no stream anywhere in the fleet can advance.  Open

@@ -172,6 +172,17 @@ class _Session:
     # swallowed when the stream re-runs them after a crash recovery
 
 
+@dataclasses.dataclass
+class _EmitPlan:
+    """What one shard emits this tick, decided on the host before any
+    hidden-state row is pulled (``StreamingEngine._finish_plan``)."""
+    rows: np.ndarray             # slots at a window end or stream end
+    deliver: np.ndarray          # of those, the ones not replay-suppressed
+    at_window: np.ndarray        # (S,) bool: window ends (reset after)
+    finished: list               # slots whose stream ends (released after)
+    advanced: int                # slots stepped this tick
+
+
 def coerce_samples(samples, input_dim: int, stream_id: str) -> np.ndarray:
     """Canonicalize fed samples to (k, input_dim) float32 — the one
     validation shared by the engine's ``feed`` and the fleet's spillover
@@ -699,9 +710,50 @@ class StreamingEngine:
         """Phase two of a tick: accept the stepped hidden states and do the
         bookkeeping — cursors, counters, trajectory taps, window/final
         emission, tumbling-window resets."""
-        avail, rows = handle
         tr, shard = self._tracer, self._obs_shard
         t_fin = tr.open("engine.finish", shard)
+        plan = self._finish_plan(handle, h_new)
+        events: list[StreamEvent] = []
+        if plan.rows.size:               # rare tick: something emits
+            # engine.emit's four children tile it: the row pull's issue,
+            # its blocking completion (which holds the wait for this
+            # tick's step), the head with the events, the window resets
+            t_emit = tr.open("engine.emit", shard)
+            t0 = tr.open("engine.emit_pull", shard)
+            deliver = plan.deliver
+            pulled = self._h_rows_issue(deliver) if deliver.size else None
+            tr.close(t0)
+            if deliver.size:
+                t0 = tr.open("engine.emit_wait", shard)
+                h_emit = self._h_rows_fetch(pulled)
+                tr.close(t0)
+                t0 = tr.open("engine.emit_head", shard)
+                events = self._emit_events(plan, h_emit)
+                tr.close(t0)
+            t0 = tr.open("engine.emit_reset", shard)
+            at_window = plan.at_window
+            if np.any(at_window):
+                self._wstep[at_window] = 0
+                if self.config.reset_on_emit:
+                    if self._device_resident:
+                        self._h = self.kernel.reset_device(
+                            self._resolve_h(), at_window)
+                        self._h_pending = None
+                    else:
+                        self._h = self.kernel.reset(self._h, at_window)
+            tr.close(t0)
+            tr.close(t_emit)
+        tr.close(t_fin)
+        return TickReport(events=events, finished=plan.finished,
+                          advanced=plan.advanced)
+
+    def _finish_plan(self, handle, h_new) -> _EmitPlan:
+        """The host half of :meth:`_advance_finish`: accept the stepped
+        hidden states, advance cursors and counters, record trajectory
+        taps, and decide which rows emit.  Touches no emitted row, so a
+        fleet front door can pull every shard's planned rows from its
+        fused output at once (``FleetEngine._step_fused``)."""
+        avail, rows = handle
         self._last_advanced = int(rows.size)
         mon = self._numerics()
         if mon is not None and not self._num_tallied \
@@ -745,62 +797,40 @@ class StreamingEngine:
         at_window = avail & (self._wstep == window)
         finished = avail & (self._total >= 0) & (self._steps >= self._total)
         emit_rows = np.nonzero(at_window | finished)[0]
-        events: list[StreamEvent] = []
-        finished_rows: list[int] = []
-        if emit_rows.size:               # rare tick: something emits
-            # engine.emit's four children tile it: the row pull's issue,
-            # its blocking completion (which holds the wait for this
-            # tick's step), the head with the events, the window resets
-            t_emit = tr.open("engine.emit", shard)
-            t0 = tr.open("engine.emit_pull", shard)
+        deliver, finished_rows = emit_rows, []
+        if emit_rows.size:
             # replay cursor: events the consumer already saw before a
-            # crash are swallowed; window-reset/finish bookkeeping below
-            # still uses the full emit set, so the recovered state
-            # transitions are identical to the uninterrupted run
+            # crash are swallowed; window-reset/finish bookkeeping still
+            # uses the full emit set, so the recovered state transitions
+            # are identical to the uninterrupted run
             deliver = emit_rows[
                 self._steps[emit_rows] > self._suppress[emit_rows]]
             self._replay_suppressed += int(emit_rows.size - deliver.size)
-            pulled = self._h_rows_issue(deliver) if deliver.size else None
-            tr.close(t0)
-            if deliver.size:
-                t0 = tr.open("engine.emit_wait", shard)
-                h_emit = self._h_rows_fetch(pulled)
-                tr.close(t0)
-                t0 = tr.open("engine.emit_head", shard)
-                logits = self.kernel.head_logits(h_emit)
-                mon = self._numerics()
-                if mon is not None:
-                    # full-histogram drift stats on the rare emission path
-                    mon.observe("h", h_emit)
-                    mon.observe("logits", logits)
-                if self.config.batch_events:
-                    events.append(self._event_batch(deliver, at_window,
-                                                    logits))
-                else:
-                    for i, slot in enumerate(deliver):
-                        kind = "window" if at_window[slot] else "final"
-                        events.append(self._event(
-                            self._sched.request_at(int(slot)), int(slot),
-                            kind, int(self._wstep[slot]), logits[i]))
-                if self._obs.metrics is not None:
-                    self._emit_metrics(deliver)
-                tr.close(t0)
-            t0 = tr.open("engine.emit_reset", shard)
             finished_rows = np.nonzero(finished)[0].tolist()
-            if np.any(at_window):
-                self._wstep[at_window] = 0
-                if self.config.reset_on_emit:
-                    if self._device_resident:
-                        self._h = self.kernel.reset_device(
-                            self._resolve_h(), at_window)
-                        self._h_pending = None
-                    else:
-                        self._h = self.kernel.reset(self._h, at_window)
-            tr.close(t0)
-            tr.close(t_emit)
-        tr.close(t_fin)
-        return TickReport(events=events, finished=finished_rows,
-                          advanced=int(rows.size))
+        return _EmitPlan(rows=emit_rows, deliver=deliver, at_window=at_window,
+                         finished=finished_rows, advanced=int(rows.size))
+
+    def _emit_events(self, plan: _EmitPlan, h_emit: np.ndarray) -> list:
+        """The head half of emission: logits of the delivered rows' pulled
+        hidden states, their numerics observation, events and metrics."""
+        deliver, at_window = plan.deliver, plan.at_window
+        logits = self.kernel.head_logits(h_emit)
+        mon = self._numerics()
+        if mon is not None:
+            # full-histogram drift stats on the rare emission path
+            mon.observe("h", h_emit)
+            mon.observe("logits", logits)
+        if self.config.batch_events:
+            events = [self._event_batch(deliver, at_window, logits)]
+        else:
+            events = [self._event(self._sched.request_at(int(slot)),
+                                  int(slot),
+                                  "window" if at_window[slot] else "final",
+                                  int(self._wstep[slot]), logits[i])
+                      for i, slot in enumerate(deliver)]
+        if self._obs.metrics is not None:
+            self._emit_metrics(deliver)
+        return events
 
     def _emit_metrics(self, deliver: np.ndarray) -> None:
         """Per-emission SLO metrics (only when a registry is attached):
@@ -855,10 +885,11 @@ class StreamingEngine:
         """Materialize the fleet-installed lazy h view, if any.  Fused
         device ticks hand each shard a ``(fused_h, lo, hi)`` spec instead
         of dispatching a per-shard device slice every tick; the first
-        row-level access (emission, tap, snapshot, reset) pays the one
-        slice.  The spec survives materialization — it is the fleet's
-        adoption token — and is cleared only when ``self._h`` is rebound
-        to an array that is no longer a view of the fused output."""
+        row-level access (tap, snapshot, admission, or emission on the
+        shard's own path) pays the one slice.  The spec survives
+        materialization — it is the fleet's adoption token — and is
+        cleared only when ``self._h`` is rebound to an array that is no
+        longer a view of the fused output."""
         if self._h is None:
             big, lo, hi = self._h_pending
             self._h = big[lo:hi]

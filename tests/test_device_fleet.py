@@ -23,6 +23,7 @@ so ``placement="devices"`` exercises real multi-device dispatch on CI.
 from __future__ import annotations
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -31,7 +32,7 @@ from repro.core import fastgrnn as fg
 from repro.core.quantization import QuantConfig, quantize_params
 from repro.kernels.fastgrnn_cell.ops import Q15StreamStep
 from repro.obs import Observability, TRANSFER_KEYS
-from repro.serve.fleet import FleetConfig, FleetEngine
+from repro.serve.fleet import FleetConfig, FleetEngine, routing
 from repro.serve.fleet.faults import ScheduledFaults
 from repro.serve.streaming import StreamingConfig, StreamingEngine
 
@@ -351,3 +352,120 @@ def test_transfer_keys_shape(qp):
         stream=StreamingConfig(max_slots=2, backend="jit")))
     tr = fleet.stats()["transfers"]
     assert set(tr) == set(TRANSFER_KEYS)
+
+
+# ---------------------------------------------------------------------------
+# Batched emission: one row pull and one window reset per device group
+# ---------------------------------------------------------------------------
+
+# "staggered" alone already has ticks where only some shards emit and a
+# stream ends on another shard's window end; the other cases add to it
+EMIT_CASES = ("staggered", "tap", "crash_replay", "rebound_shard")
+
+
+def _staggered(streams, shards):
+    """Per-stream start ticks and lengths, from each stream's home shard:
+    a stream starts on the tick of its shard's index, so shards reach
+    their window ends on different ticks; every fourth stream ends 24
+    ticks after another used shard's start, on that shard's window end."""
+    ids = sorted(streams)
+    home = {sid: routing.route(sid, [f"shard-{i}" for i in range(shards)],
+                               [True] * shards) for sid in ids}
+    used = sorted(set(home.values()))
+    lengths = {}
+    for k, sid in enumerate(ids):
+        other = [b for b in used if b != home[sid]]
+        lengths[sid] = (24 + other[0] - home[sid] if k % 4 == 3 and other
+                        else len(streams[sid]))
+    return home, {sid: streams[sid][:lengths[sid]] for sid in ids}
+
+
+def _emission_run(qp, streams, *, backend, placement, shards, case,
+                  monkeypatch):
+    """Feed every stream one sample a tick from its start tick through a
+    device-resident fused fleet; returns the event log, the trajectories
+    of tapped streams, the per-tick emitting shards, and the fleet."""
+    home, streams = _staggered(streams, shards)
+    ids = sorted(streams)
+    taps = set(ids[::3]) if case == "tap" else set()
+    # crash the home shard of the first stream the tick after its first
+    # window end: the replay from the last snapshot re-runs that event,
+    # which the replay cursor swallows
+    victim = home[ids[0]]
+    first = victim + 8
+    faults = ScheduledFaults(schedule=[
+        (first + 1, "pre_tick", victim), (first + 4, "mid_dispatch", victim),
+        (first + 10, "post_emit", victim)]) if case == "crash_replay" else None
+    fleet = FleetEngine(qp, FleetConfig(
+        shards=shards, placement=placement,
+        stream=StreamingConfig(max_slots=len(streams), window=8,
+                               backend=backend, device_resident=True),
+        snapshot_every=5), faults=faults)
+    if case == "rebound_shard":
+        # rebind one shard's h after every other dispatch (as an
+        # admission or a restore would): it finishes on its own path
+        real = fleet._dispatch_group
+
+        def dispatch(g, begun, h_out):
+            real(g, begun, h_out)
+            sh = fleet.shards[victim]
+            if victim in g.idxs and fleet._ticks % 2 and \
+                    sh._h_pending is not None:
+                sh._h = jnp.copy(sh._resolve_h())
+                sh._h_pending = None
+        monkeypatch.setattr(fleet, "_dispatch_group", dispatch)
+    for sid, w in streams.items():
+        fleet.attach(sid, total_steps=len(w), record_trajectory=sid in taps)
+    log: dict = {}
+    emitting: list[tuple[set, set]] = []
+    end = max(home[s] + len(w) for s, w in streams.items())
+    t = 0
+    while t < end or fleet._any_buffered():
+        for sid, w in streams.items():
+            if 0 <= t - home[sid] < len(w):
+                fleet.feed(sid, w[t - home[sid]][None])
+        events = fleet.step()
+        collect_log(events, log)
+        emitting.append(({home[e.stream_id] for e in events
+                          if e.kind == "window"},
+                         {home[e.stream_id] for e in events
+                          if e.kind == "final"}))
+        t += 1
+    trajs = {sid: fleet.trajectory(sid) for sid in taps}
+    return streams, log, trajs, emitting, fleet
+
+
+@pytest.mark.parametrize("case", EMIT_CASES)
+@pytest.mark.parametrize("shards", [1, 2, 4, 8])
+@pytest.mark.parametrize("backend,placement", [
+    ("jit", "host"), ("jit", "devices"), ("pallas", "host")])
+def test_batched_emission_byte_identical(qp, streams, backend, placement,
+                                         shards, case, monkeypatch):
+    """The fused resident fleet's batched emission gives the single
+    engine's events byte for byte: on ticks where only some shards emit,
+    where a stream ends on another's window end, with trajectory taps,
+    after a crash whose replay suppresses delivered events, and when a
+    shard whose h was rebound finishes on its own pull."""
+    own = []
+    real_finish = StreamingEngine._advance_finish
+    monkeypatch.setattr(StreamingEngine, "_advance_finish",
+                        lambda self, *a: own.append(1) or real_finish(self, *a))
+    cut, got, trajs, emitting, fleet = _emission_run(
+        qp, streams, backend=backend, placement=placement, shards=shards,
+        case=case, monkeypatch=monkeypatch)
+    assert bool(own) == (case == "rebound_shard")
+    ref = StreamingEngine(qp, StreamingConfig(
+        max_slots=len(cut), window=8, backend=backend))
+    for sid, w in cut.items():
+        ref.attach(sid, w, total_steps=len(w),
+                   record_trajectory=sid in trajs)
+    assert_logs_identical(got, collect_log(ref.drain()))
+    for sid, traj in trajs.items():
+        assert traj.tobytes() == ref.trajectory(sid).tobytes()
+    st = fleet.stats()
+    if shards > 1 and case != "crash_replay":   # a replay shifts timing
+        used = {s for w, f in emitting for s in w | f}
+        assert any(w | f and w | f != used for w, f in emitting)
+        assert any(f - w and w for w, f in emitting)
+    if case == "crash_replay":
+        assert st["failovers"] == 3 and st["replay_suppressed"] > 0

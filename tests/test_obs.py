@@ -528,8 +528,8 @@ def test_fleet_feed_counted_outside_the_flight_ring(qp, input_dim):
 
 def test_resident_tick_books_its_crossings(qp, input_dim):
     """A steady resident tick copies x and the active mask to the device
-    and pulls nothing; an emitting tick adds, per emitting shard, the
-    row indices and the window-reset mask up and the rows down."""
+    and pulls nothing; an emitting tick adds, once for the device group,
+    the row indices and the window-reset mask up and the rows down."""
     fleet = _resident_fleet(qp)
     for sid, w in make_streams(8, 24, input_dim).items():
         fleet.attach(sid, w)
@@ -541,7 +541,26 @@ def test_resident_tick_books_its_crossings(qp, input_dim):
         after = fleet.stats()["transfers"]
         crossings.append((after["h2d_count"] - before["h2d_count"],
                           after["d2h_count"] - before["d2h_count"]))
-    assert crossings == [(2, 0)] * 6 + [(2 + 2 * 2, 2)]
+    assert crossings == [(2, 0)] * 6 + [(2 + 2, 1)]
+
+
+def test_resident_tick_after_emission_adopts_the_fused_state(qp, input_dim,
+                                                             monkeypatch):
+    """The window reset's output is the group's fused state, so the tick
+    after an emitting tick steps it as it is: no device concatenate."""
+    fleet = _resident_fleet(qp)
+    for sid, w in make_streams(8, 24, input_dim).items():
+        fleet.attach(sid, w)
+    g, = fleet._group_list
+    concats = []
+    real = g.kernel.concat_device
+    monkeypatch.setattr(g.kernel, "concat_device",
+                        lambda parts: concats.append(len(parts)) or real(parts))
+    fleet.step()                         # admission: the one concatenate
+    assert concats == [2]
+    for tick in range(2, 18):            # windows end at ticks 8 and 16
+        fleet.step()
+        assert concats == [2], tick
 
 
 def test_fleet_tick_phases_traced(qp, input_dim):
@@ -572,6 +591,19 @@ def test_fleet_tick_phases_traced(qp, input_dim):
     shards = {r["shard"] for r in obs.tracer.flight()
               if r["phase"] == "engine.gather"}
     assert shards <= {0, 1} and shards
+    # the fused resident fleet emits once per device group: its two
+    # shards' emitting ticks open engine.emit and each child once
+    obs = Observability.full()
+    fleet = _resident_fleet(qp, obs)
+    for sid, w in make_streams(8, 24, input_dim).items():
+        fleet.attach(sid, w)
+    for _ in range(17):                  # windows end at ticks 8 and 16
+        fleet.step()
+    st = obs.tracer.phase_stats()
+    assert st["engine.finish"]["count"] == 2 * 17
+    assert all(st[k]["count"] == 2 for k in ("engine.emit",) + kids)
+    assert sum(st[k]["total_us"] for k in kids) <= \
+        st["engine.emit"]["total_us"]
 
 
 def test_single_engine_kernel_span_and_tick(qp, input_dim):
