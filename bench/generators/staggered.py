@@ -48,9 +48,9 @@ def check_mix(mix: dict) -> dict:
 class Generator:
     """The schedule and samples of one run of a mix."""
 
-    def __init__(self, mix: dict, window: int, seed_seq: np.random.SeedSequence):
+    def __init__(self, mix: dict, model: dict, seed_seq: np.random.SeedSequence):
         check_mix(mix)
-        self.mix, self.window = mix, window
+        self.mix, self.window = mix, model["window"]
         n, self.packet = mix["streams"], mix["packet_samples"]
         self.phases = mix["window_phases"]
         rng = np.random.default_rng(seed_seq)
@@ -103,9 +103,11 @@ class Generator:
         for j, i in enumerate(idx):
             system.feed(ids[i], pk[j])
 
-    def expected(self, last_tick: int) -> dict:
+    def expected(self, last_tick: int, emitted=None) -> dict:
         """{(stream id, step): window samples} of every prediction the
-        sampled streams were due to emit once ``last_tick`` has run."""
+        sampled streams were due to emit once ``last_tick`` has run.  A
+        window's input does not depend on what the fleet emitted, so
+        ``emitted`` is not read."""
         done = self.windows_done(last_tick)
         out = {}
         for i in self.check:

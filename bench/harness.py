@@ -2,25 +2,44 @@
 
 Everything is found by name.  ``BENCHMARK.json`` names the cell, its
 configuration (a file of sizes, which names its system under test in
-``systems/`` and its plain reference in ``references/``) and its traffic
-mix (``traffic/<name>.json``, whose ``generator`` key names the module
+``systems/``, its plain reference in ``references/`` and, by its
+``model.cell``, its work count in ``work/``) and its traffic mix
+(``traffic/<name>.json``, whose ``generator`` key names the module
 ``generators/<generator>.py`` that reads it); every metric is a reader
 ``metrics/<name>.py`` with ``read(ctx) -> float | None``.  A later cell,
-mix, generator or metric is a new file and a new entry, never an edit here.
+configuration, mix, generator or metric is a new file and a new entry,
+never an edit here.
 
-A generator module has a class ``Generator(mix, window, seed_seq)`` with
-``capacity`` (the most streams attached at once) and ``max_buffered``
-(samples a stream holds at most), which size the system, ``rollin_ticks``, ``check_ids`` (the sampled streams),
-``setup(system)`` (attach before warm-up), ``prepare(tick)`` (made
-outside the timed tick), ``drive(system, prepared)`` (feeds, attaches,
-detaches: inside it) and ``expected(last_tick)``, which maps (stream id,
-step) of every prediction the sampled streams were due to emit to its
-window of samples.
+A generator module has a class ``Generator(mix, model, seed_seq)``, where
+``model`` is the configuration's ``model`` section, with ``capacity`` (the
+most streams attached at once) and ``max_buffered`` (samples a stream
+holds at most), which size the system, ``rollin_ticks``, ``check_ids``
+(the sampled streams), ``setup(system)`` (attach before warm-up),
+``prepare(tick)`` (made outside the timed tick), ``drive(system,
+prepared)`` (feeds, attaches, detaches: inside it) and
+``expected(last_tick, emitted)``, which maps (stream id, step) of every
+prediction the sampled streams were due to emit once ``last_tick`` has
+run to the reference's input for it.  ``emitted`` maps (stream id, step)
+to what the program chose there (see the system's ``step``), so the input
+of a sequence's step k can be its prompt and the program's first k tokens.
 
-One tick: the generator drives the system (``bench.feed``), the fleet
+A system module has a class ``System(cfg, params, *, slots, ring, bits,
+trace)`` whose ``step()`` returns the tick's emitted batches as
+(stream_ids, steps, logits) or (stream_ids, steps, logits, choices), the
+choice being what the program picked from each row (an emitted token).
+A reference module has ``make_params(cfg, seed_seq)`` and a class
+``Reference(cfg, params)`` whose ``logits(inputs)`` takes the expected
+inputs as a list in key order and returns one row of logits for each.
+A work module has ``count(model, counters) -> {"flops", "hbm_bytes"}``:
+the operations and bytes the window's work needs, from the model section
+and the counters' deltas over the window (``counters["stream_steps"]``
+among them); readers get it as ``ctx["work"]``.
+
+One tick: the generator drives the system (``bench.feed``), the system
 steps once (``bench.step``), and the host waits until the tick's device
 work is done (``bench.sync``).  Set-up rolls the streams in and runs a few
-ticks more, so every shape the window uses is compiled before it opens.
+ticks more (past a window, for a windowed model), so every shape the
+window uses is compiled before it opens.
 A closed loop starts each tick when the previous one is done and its
 inputs are prepared; an open loop starts tick k at t0 + k / tick_hz, or
 late, never skipped.  A tick's latency runs from when it was due to when
@@ -41,7 +60,6 @@ import numpy as np
 
 import check
 import trace_reduce
-import work
 
 WARM_TICKS = 4          # past the roll-in and the first window's end
 COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
@@ -133,10 +151,10 @@ class Bench:
         model_ss, traffic_ss = np.random.SeedSequence(seed % 2**64).spawn(2)
         ref_mod = _load_module(self.find("references", cfg["reference"], ".py"))
         sys_mod = _load_module(self.find("systems", cfg["serving"]["system"], ".py"))
+        work_mod = _load_module(self.find("work", cfg["model"]["cell"], ".py"))
         params = ref_mod.make_params(cfg, model_ss)
-        window = cfg["model"]["window"]
         gen_mod = _load_module(self.find("generators", mix["generator"], ".py"))
-        traffic = gen_mod.Generator(mix, window, traffic_ss)
+        traffic = gen_mod.Generator(mix, cfg["model"], traffic_ss)
         ring = 1 << max(traffic.max_buffered, 1).bit_length()
         system = sys_mod.System(cfg, params, slots=traffic.capacity, ring=ring,
                                 bits=bits, trace=trace)
@@ -156,7 +174,8 @@ class Bench:
                 system.sync()
             tick += 1
 
-        for _ in range(max(traffic.rollin_ticks, window) + WARM_TICKS):
+        for _ in range(max(traffic.rollin_ticks, cfg["model"].get("window", 0))
+                       + WARM_TICKS):
             run_tick(traffic.prepare(tick))
         setup_s = time.perf_counter() - t_start
 
@@ -209,18 +228,19 @@ class Bench:
 
         # the check: after the window, with the program's state freed
         reference = ref_mod.Reference(cfg, params)
-        nums = check.numbers(check.collect(log, traffic.check_ids),
-                             traffic.expected(tick - 1), reference)
+        got, chosen = check.collect(log, traffic.check_ids)
+        nums = check.numbers(got, traffic.expected(tick - 1, chosen), reference,
+                             cfg["check"], chosen)
         correct, checks, failed = check.verdict(nums, cfg["check"])
+        counters = {k: c1[k] - c0[k] for k in c1}
 
         ctx = {
             "setup_s": setup_s, "window_s": t1 - t0, "ticks": len(lat),
             "system_s": float(np.sum(lat)) if mix["loop"] == "closed" else t1 - t0,
             "latencies_s": np.asarray(lat), "generator_s": gen_s,
-            "stream_steps": c1["stream_steps"] - c0["stream_steps"],
-            "counters": {k: c1[k] - c0[k] for k in c1},
+            "stream_steps": counters["stream_steps"], "counters": counters,
             "spans": {k: v - s0.get(k, 0.0) for k, v in s1.items()},
-            "trace": reduced, "work": work.per_stream_step(cfg["model"]),
+            "trace": reduced, "work": work_mod.count(cfg["model"], counters),
             "peak": self.peak(used[0].device_kind) if require_chip else None,
             "chips": wl["chips"],
         }

@@ -1,10 +1,10 @@
-"""The whole tick's share of the chips' peak, in percent: stream-steps per
-second times model FLOPs per stream-step (``work.py``) over chips times the
+"""The whole tick's share of the chips' peak, in percent: the window's
+model FLOPs (``work/fastgrnn.py``: FLOPs per stream-step times the
+stream-steps advanced) over the system's seconds, over chips times the
 published peak FLOP/s.  Host clock and counter."""
 
 
 def read(ctx):
-    if not ctx["peak"] or not ctx["stream_steps"]:
+    if not ctx["peak"] or not ctx["work"]["flops"]:
         return None
-    rate = ctx["stream_steps"] / ctx["system_s"]
-    return 100.0 * rate * ctx["work"]["flops"] / (ctx["chips"] * ctx["peak"]["flops"])
+    return 100.0 * ctx["work"]["flops"] / ctx["system_s"] / (ctx["chips"] * ctx["peak"]["flops"])

@@ -1,7 +1,8 @@
 """The Q15 step kernel's share of its roofline, in percent: the least time
-the chip could take for the window's stream-steps, max(FLOPs / peak FLOP/s,
-bytes / HBM bandwidth) counted from the model's shapes (``work.py``), over
-the device time of the kernel's ops in the trace.  Device trace.
+the chip could take for the window's work, max(FLOPs / peak FLOP/s,
+bytes / HBM bandwidth) counted from the model's shapes and the window's
+stream-steps (``work/fastgrnn.py``), over the device time of the kernel's
+ops in the trace.  Device trace.
 
 The kernel's op is found by its name or its stats: the step's Pallas
 kernel (``_q15_step_kernel``), which is the only custom call a fleet tick
@@ -21,6 +22,6 @@ def read(ctx):
     if t <= 0:
         raise LookupError(f"no step-kernel op ({', '.join(KEYS)}) among the "
                           f"{len(tr['ops'])} device ops of the trace")
-    n, w = ctx["stream_steps"], ctx["work"]
-    bound = max(n * w["flops"] / peak["flops"], n * w["hbm_bytes"] / peak["hbm_bytes_per_s"])
+    w = ctx["work"]
+    bound = max(w["flops"] / peak["flops"], w["hbm_bytes"] / peak["hbm_bytes_per_s"])
     return 100.0 * bound / (t / tr["devices"])

@@ -128,8 +128,9 @@ class Reference:
         h_tilde = self._lut(self.tanh, pre + w["b_h"])
         return ((self.zeta * (1.0 - z) + self.nu) * h_tilde + z * h).astype(np.float32)
 
-    def logits(self, windows: np.ndarray, block: int = 8192) -> np.ndarray:
-        """(n, T, d) windows -> (n, C) logits, each window from h = 0."""
+    def logits(self, windows: list, block: int = 8192) -> np.ndarray:
+        """A list of n (T, d) windows -> (n, C) logits, each window from
+        h = 0."""
         out = []
         for s in range(0, len(windows), block):
             xs = np.asarray(windows[s:s + block], np.float32)

@@ -65,7 +65,8 @@ def test_roofline_reader_finds_the_kernel_by_name_or_stats():
     trace = {"devices": 1, "ops": {"custom-call.7": 0.002, "fusion.1": 0.001},
              "op_text": {"custom-call.7": "", "fusion.1": ""}}
     ctx = {"trace": trace, "peak": {"flops": 197e12, "hbm_bytes_per_s": 819e9},
-           "stream_steps": 131072, "work": {"flops": 748, "hbm_bytes": 140}}
+           "stream_steps": 131072,
+           "work": {"flops": 131072 * 748, "hbm_bytes": 131072 * 140}}
     bound = 131072 * 140 / 819e9
     assert read(ctx) == pytest.approx(100 * bound / 0.002)
     trace["ops"] = {"fusion.2": 0.004}
@@ -107,5 +108,5 @@ def test_recorded_v5e_trace():
     with open(os.path.join(BENCH, "peaks.json")) as f:
         peak = json.load(f)["devices"]["TPU v5 lite"]
     ctx = {"trace": r, "peak": peak, "stream_steps": 8 * 1024,
-           "work": {"flops": 748, "hbm_bytes": 140}}
+           "work": {"flops": 8 * 1024 * 748, "hbm_bytes": 8 * 1024 * 140}}
     assert read(ctx) == pytest.approx(0.3917051181519054)
