@@ -4,7 +4,7 @@ FastGRNN HAR deployment config.  ``get(name)`` returns a ModelConfig;
 from .base import ModelConfig, ShapeConfig, SHAPES, applicable  # noqa: F401
 
 from . import (minitron_4b, qwen2_1_5b, deepseek_7b, nemotron_4_340b,
-               olmoe_1b_7b, moonshot_v1_16b_a3b, internvl2_76b,
+               olmoe_1b_7b, moonlight_16b_a3b, internvl2_76b,
                zamba2_1_2b, hubert_xlarge, mamba2_780m, fastgrnn_har)  # noqa: F401
 
 ARCHS = {
@@ -13,7 +13,7 @@ ARCHS = {
     "deepseek-7b": deepseek_7b.CONFIG,
     "nemotron-4-340b": nemotron_4_340b.CONFIG,
     "olmoe-1b-7b": olmoe_1b_7b.CONFIG,
-    "moonshot-v1-16b-a3b": moonshot_v1_16b_a3b.CONFIG,
+    "moonlight-16b-a3b": moonlight_16b_a3b.CONFIG,
     "internvl2-76b": internvl2_76b.CONFIG,
     "zamba2-1.2b": zamba2_1_2b.CONFIG,
     "hubert-xlarge": hubert_xlarge.CONFIG,
@@ -45,5 +45,11 @@ def reduced(cfg: ModelConfig, **overrides) -> ModelConfig:
         ssd_chunk=32,
         remat=False,
     )
+    if cfg.uses_mla:           # MLA + DeepSeek MoE: every kind of layer kept
+        small.update(
+            num_layers=cfg.first_k_dense_replace + 2, head_dim=0,
+            kv_lora_rank=32, qk_nope_head_dim=16, qk_rope_head_dim=8,
+            v_head_dim=16, num_experts=8, top_k=2, moe_d_ff=32,
+            n_shared_experts=min(cfg.n_shared_experts, 1))
     small.update(overrides)
     return _dc.replace(cfg, **small)

@@ -24,10 +24,22 @@ class ModelConfig:
     tie_embeddings: bool = False
     rope_theta: float = 10_000.0
     norm_eps: float = 1e-5
+    # multi-head latent attention (DeepSeek-V2/V3 MLA): on when
+    # kv_lora_rank > 0; q is a plain projection (q_lora_rank null)
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
     # MoE
     num_experts: int = 0
     top_k: int = 0
     capacity_factor: float = 1.25
+    moe_d_ff: int = 0               # expert width; 0 -> d_ff
+    n_shared_experts: int = 0       # shared experts, one SwiGLU of n x moe_d_ff
+    first_k_dense_replace: int = 0  # leading layers with a dense MLP of d_ff
+    router_scoring: str = "softmax"  # softmax (capacity) | sigmoid (dropless)
+    routed_scaling_factor: float = 1.0
+    norm_topk_prob: bool = True
     # SSM / hybrid
     ssm_state: int = 0
     mamba_headdim: int = 64
@@ -61,6 +73,21 @@ class ModelConfig:
     @property
     def cdtype(self):
         return jnp.dtype(self.compute_dtype)
+
+    @property
+    def uses_mla(self) -> bool:
+        return self.kv_lora_rank > 0
+
+    @property
+    def moe_dropless(self) -> bool:
+        """Sigmoid-routed MoE (DeepSeek-V3's ``noaux_tc``): every token
+        reaches its top-k experts, in training and serving alike; softmax
+        routing keeps its capacity factor."""
+        return self.family == "moe" and self.router_scoring == "sigmoid"
+
+    @property
+    def expert_d_ff(self) -> int:
+        return self.moe_d_ff or self.d_ff
 
     @property
     def uses_attention(self) -> bool:

@@ -80,6 +80,12 @@ def _leaf_rule(tokens: list[str], shape: tuple[int, ...], mesh) -> P:
             if name == "w":                         # (N*hd, D)
                 return P(ax("model", 0), ax("data", 1))
             return P(None)
+        # MLA: the latent down-projection is small and shared by every
+        # head; the up-projection splits by head like q
+        if proj == "kv_a" and name == "w":          # (D, r + rope)
+            return P(ax("data", 0), None)
+        if proj == "kv_b" and name == "w":          # (r, N*(nope+v))
+            return P(None, ax("model", 1))
 
     # MoE: router (D,E); experts (E, d_in, d_out)
     if "moe" in t:
@@ -192,7 +198,7 @@ def param_pspecs(abstract_params, mesh, *, seq_parallel: bool = False,
     specs = []
     for path, leaf in flat:
         tokens = _TOKEN_RE.findall(jax.tree_util.keystr(path))
-        stacked = tokens and tokens[0] == "blocks"
+        stacked = tokens and tokens[0] in ("blocks", "dense")
         shape = tuple(leaf.shape)
         rule = (lambda t, s: _sp_dense_leaf_rule(t, s, mesh, kv_shardable)) \
             if mode == "sp_dense" else (lambda t, s: _leaf_rule(t, s, mesh))

@@ -361,3 +361,118 @@ def attn_decode(p, x, cache_k, cache_v, cache_len, cfg, *,
     o = L.dense_apply(p["o"], o.reshape(x.shape[:-1] + (H * hd,)),
                       compute_dtype=compute_dtype)
     return o, new_k, new_v
+
+
+# ---------------------------------------------------------------------------
+# Multi-head latent attention (DeepSeek-V2/V3 MLA with q_lora_rank null)
+# ---------------------------------------------------------------------------
+#
+#   q            = x W_q                       -> [q_nope | q_pe] per head
+#   [c_kv | k_pe] = x W_kva;  c_kv = RMSNorm(c_kv)
+#   [k_nope | v] = c_kv W_kvb                  per head
+#   RoPE on q_pe and the head-shared k_pe (interleaved pairs); scale
+#   (nope + rope)^-0.5.  Full sequences (prefill, training) use this form;
+#   decode absorbs W_UK into the query and W_UV into the output and reads
+#   only the cached latent row [c_kv | k_pe] of each position.
+
+MLA_KV_NORM_EPS = 1e-6      # DeepSeek-V3 builds kv_a_layernorm at its default eps
+MLA_BLOCK = 512             # query block of full-sequence attention
+
+
+def mla_init(key, cfg, dtype=jnp.float32):
+    H, r = cfg.num_heads, cfg.kv_lora_rank
+    nope, rope, vd = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    ks = jax.random.split(key, 4)
+    return {
+        "q": L.dense_init(ks[0], cfg.d_model, H * (nope + rope), dtype=dtype),
+        "kv_a": L.dense_init(ks[1], cfg.d_model, r + rope, dtype=dtype),
+        "kv_norm": L.rmsnorm_init(r, dtype),
+        "kv_b": L.dense_init(ks[2], r, H * (nope + vd), dtype=dtype),
+        "o": L.dense_init(ks[3], H * vd, cfg.d_model, dtype=dtype),
+    }
+
+
+def rope_interleaved(x, positions, theta: float):
+    """RoPE over interleaved pairs (x[2i], x[2i+1]), rotated by
+    pos * theta^(-2i/d): the pairing DeepSeek-V3's modeling code makes by
+    de-interleaving before its rotate-half.  x: (..., S, H, d);
+    positions: (..., S)."""
+    d = x.shape[-1]
+    ang = positions[..., None].astype(jnp.float32) * L.rope_freqs(d, theta)
+    cos, sin = jnp.cos(ang)[..., None, :], jnp.sin(ang)[..., None, :]
+    xf = x.astype(jnp.float32).reshape(x.shape[:-1] + (d // 2, 2))
+    x1, x2 = xf[..., 0], xf[..., 1]
+    out = jnp.stack([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
+    return out.reshape(x.shape).astype(x.dtype)
+
+
+def mla_qkv(p, x, positions, cfg, *, compute_dtype=jnp.bfloat16):
+    """-> (q_nope (B,S,H,nope), q_pe roped (B,S,H,rope), latent (B,S,r+rope)):
+    the latent row is what the cache keeps, the normed c_kv beside the
+    roped k_pe."""
+    H, r = cfg.num_heads, cfg.kv_lora_rank
+    nope, rope = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
+    q = _split_heads(L.dense_apply(p["q"], x, compute_dtype=compute_dtype),
+                     H, nope + rope)
+    q_pe = rope_interleaved(q[..., nope:], positions, cfg.rope_theta)
+    kv = L.dense_apply(p["kv_a"], x, compute_dtype=compute_dtype)
+    c = L.rmsnorm_apply(p["kv_norm"], kv[..., :r], MLA_KV_NORM_EPS)
+    k_pe = rope_interleaved(kv[..., None, r:], positions, cfg.rope_theta)[..., 0, :]
+    return q[..., :nope], q_pe, jnp.concatenate([c, k_pe], axis=-1)
+
+
+def _causal_blocked(q, k, v, block: int = MLA_BLOCK):
+    """Causal attention in query blocks, so the (S, S) scores never exist
+    whole.  q, k: (B, S, H, dq); v: (B, S, H, dv) -> (B, S, H, dv)."""
+    b, s, h, _ = q.shape
+    if s <= block:
+        return attention_scores(q, k, v, causal=True)
+    n = -(-s // block)
+    qp = jnp.pad(q, ((0, 0), (0, n * block - s), (0, 0), (0, 0)))
+    qb = jnp.moveaxis(qp.reshape(b, n, block, h, q.shape[-1]), 1, 0)
+
+    def body(_, xs):
+        i, qx = xs
+        return None, attention_scores(qx, k, v, causal=True, q_offset=i * block)
+    _, out = jax.lax.scan(body, None, (jnp.arange(n), qb))
+    return jnp.moveaxis(out, 0, 1).reshape(b, n * block, h, v.shape[-1])[:, :s]
+
+
+def mla_apply(p, x, positions, cfg, *, compute_dtype=jnp.bfloat16):
+    """Full-sequence causal MLA in the published (non-absorbed) form.
+    x: (B, S, D) -> (out (B, S, D), latent (B, S, r+rope) for the cache)."""
+    H, r = cfg.num_heads, cfg.kv_lora_rank
+    nope, vd = cfg.qk_nope_head_dim, cfg.v_head_dim
+    q_nope, q_pe, lat = mla_qkv(p, x, positions, cfg, compute_dtype=compute_dtype)
+    kv = _split_heads(L.dense_apply(p["kv_b"], lat[..., :r],
+                                    compute_dtype=compute_dtype), H, nope + vd)
+    k_pe = jnp.broadcast_to(lat[..., None, r:], q_pe.shape)
+    q = jnp.concatenate([q_nope, q_pe.astype(q_nope.dtype)], axis=-1)
+    k = jnp.concatenate([kv[..., :nope], k_pe.astype(kv.dtype)], axis=-1)
+    o = _causal_blocked(q, k, kv[..., nope:])
+    o = L.dense_apply(p["o"], o.reshape(x.shape[:-1] + (H * vd,)),
+                      compute_dtype=compute_dtype)
+    return o, lat
+
+
+def mla_decode_attend(p, q_nope, q_pe, ckv, kpe, layer, pos, cfg, *,
+                      compute_dtype=jnp.bfloat16):
+    """Absorbed single-token MLA over a latent cache.  q_nope/q_pe:
+    (B, 1, H, .) of the new tokens; ``ckv`` (L, B, T, r) and ``kpe``
+    (L, B, T/2, 2 rope): the cache with the new rows already written (see
+    ``kernels/mla_decode``); ``layer``: the cache layer; ``pos``: (B,) —
+    row b attends over positions 0..pos[b].  W_UK folds into the query and
+    W_UV into the output, so only the attended latent rows are read."""
+    from repro.kernels.mla_decode import mla_decode_attention
+    H, r = cfg.num_heads, cfg.kv_lora_rank
+    nope, rope, vd = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    cd = compute_dtype
+    w = p["kv_b"]["w"].astype(cd).reshape(r, H, nope + vd)
+    q_lat = jnp.einsum("bhn,rhn->bhr", q_nope[:, 0].astype(cd), w[..., :nope],
+                       preferred_element_type=jnp.float32).astype(cd)
+    o_lat = mla_decode_attention(q_lat, q_pe[:, 0].astype(cd), ckv, kpe, layer, pos,
+                                 scale=(nope + rope) ** -0.5)
+    o = jnp.einsum("bhr,rhv->bhv", o_lat.astype(cd), w[..., nope:],
+                   preferred_element_type=jnp.float32).astype(cd)
+    return L.dense_apply(p["o"], o.reshape(o.shape[0], 1, H * vd),
+                         compute_dtype=cd)

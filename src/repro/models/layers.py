@@ -14,7 +14,7 @@ import numpy as np
 
 
 def truncated_normal(key, shape, std=0.02, dtype=jnp.float32):
-    return std * jax.random.truncated_normal(key, -2.0, 2.0, shape, jnp.float32).astype(dtype)
+    return (std * jax.random.truncated_normal(key, -2.0, 2.0, shape, jnp.float32)).astype(dtype)
 
 
 # ---------------------------------------------------------------------------

@@ -19,6 +19,14 @@ Design (production-oriented, collective-explicit):
 
 Aux losses: Switch load-balance + router z-loss, computed from local
 routing statistics (averaged over data shards by the outer loss mean).
+
+``moe_dropless`` is the DeepSeek-V3 layer (sigmoid scoring, ``noaux_tc``
+top-k on bias-corrected scores, normalised and scaled weights, shared
+experts): every token reaches all its experts, grouped by expert and
+computed with ``jax.lax.ragged_dot``, so the expert FLOPs are those of the
+routed tokens and nothing is dropped at any batch size.  A scan over its
+layers keeps the routed experts' weights out of the sliced blocks
+(``split_experts``) and hands the whole stack to each layer.
 """
 from __future__ import annotations
 
@@ -29,8 +37,8 @@ from . import layers as L
 
 
 def moe_init(key, d_model: int, d_ff: int, num_experts: int, kind: str = "swiglu",
-             dtype=jnp.float32):
-    ks = jax.random.split(key, 4)
+             dtype=jnp.float32, *, n_shared: int = 0, dropless: bool = False):
+    ks = jax.random.split(key, 5)
     def ex(k, d_in, d_out):
         return L.truncated_normal(k, (num_experts, d_in, d_out),
                                   1.0 / (d_in ** 0.5), dtype)
@@ -39,6 +47,10 @@ def moe_init(key, d_model: int, d_ff: int, num_experts: int, kind: str = "swiglu
          "w_out": ex(ks[2], d_ff, d_model)}
     if kind in ("swiglu", "geglu"):
         p["w_gate"] = ex(ks[3], d_model, d_ff)
+    if dropless:                    # noaux_tc: e_score_correction_bias
+        p["e_bias"] = jnp.zeros((num_experts,), jnp.float32)
+    if n_shared:
+        p["shared"] = L.mlp_init(ks[4], d_model, n_shared * d_ff, kind, dtype=dtype)
     return p
 
 
@@ -130,3 +142,76 @@ def moe_apply(p, x, *, top_k: int, capacity_factor: float = 1.25,
         p, x, num_experts_global=e, expert_offset=jnp.int32(0), top_k=top_k,
         capacity_factor=capacity_factor, kind=kind, model_axis=None,
         compute_dtype=compute_dtype)
+
+
+def route_sigmoid(p, x, *, top_k: int, norm_topk_prob: bool = True,
+                  scaling: float = 1.0):
+    """DeepSeek-V3 ``noaux_tc`` routing with one group.  x: (n, D) ->
+    (weights (n, k) f32, expert ids (n, k) int32): the top k of
+    ``sigmoid(x W_r) + bias`` in f32; the weights are the chosen scores
+    without the bias, normalised to sum 1, times ``scaling``."""
+    scores = jax.nn.sigmoid(L.dense_apply(p["router"], x.astype(jnp.float32),
+                                          compute_dtype=jnp.float32))
+    _, ids = jax.lax.top_k(scores + p["e_bias"], top_k)
+    w = jnp.take_along_axis(scores, ids, axis=-1)
+    if norm_topk_prob:
+        w = w / (w.sum(-1, keepdims=True) + 1e-20)
+    return w * scaling, ids.astype(jnp.int32)
+
+
+EXPERT_KEYS = ("w_in", "w_gate", "w_out")
+
+
+def split_experts(cfg, blocks):
+    """(blocks without the routed experts' weights, those weights stacked
+    over every MoE layer) for a scan over dropless MoE layers: sliced per
+    layer by the scan, each layer's experts would be copied whole, since
+    the grouped matmul cannot read a slice in place.  (blocks, None) where
+    the blocks hold no dropless MoE."""
+    moe = blocks.get("moe")
+    if moe is None or not cfg.moe_dropless:
+        return blocks, None
+    rest = dict(blocks, moe={k: v for k, v in moe.items() if k not in EXPERT_KEYS})
+    return rest, {k: moe[k] for k in EXPERT_KEYS if k in moe}
+
+
+def moe_dropless(p, x, cfg, experts, layer, *, compute_dtype=jnp.bfloat16):
+    """Dropless DeepSeek-V3 MoE.  x: (B, S, D) -> (y (B, S, D), expert ids
+    (B, S, k)).  ``p``: the layer's router, correction bias and shared
+    experts; ``experts``: the routed experts of every MoE layer stacked
+    (L, E, ., .), this layer's at index ``layer``.  The n*k (token,
+    expert) pairs are sorted by expert and run through ``ragged_dot`` per
+    projection over all L*E groups, the other layers' groups empty, so no
+    layer's weights are copied out of the stack; the shared experts see
+    every token."""
+    b, s, d = x.shape
+    k = cfg.top_k
+    nl, e = experts["w_in"].shape[:2]
+    xt = x.reshape(b * s, d)
+    w, ids = route_sigmoid(p, xt, top_k=k, norm_topk_prob=cfg.norm_topk_prob,
+                           scaling=cfg.routed_scaling_factor)
+    cd = compute_dtype
+    with jax.named_scope("moe_experts"):
+        flat = ids.reshape(-1)
+        order = jnp.argsort(flat, stable=True)
+        sizes = jax.lax.dynamic_update_slice(
+            jnp.zeros((nl * e,), jnp.int32), jnp.bincount(flat, length=e).astype(jnp.int32),
+            (layer * e,))
+        xs = jnp.take(xt.astype(cd), order // k, axis=0)
+        rd = lambda a, name: jax.lax.ragged_dot(
+            a, experts[name].reshape((nl * e,) + experts[name].shape[2:]).astype(cd),
+            sizes, preferred_element_type=jnp.float32)
+        h = rd(xs, "w_in")
+        if cfg.mlp_kind in ("swiglu", "geglu"):
+            act = jax.nn.silu if cfg.mlp_kind == "swiglu" else jax.nn.gelu
+            h = act(rd(xs, "w_gate")) * h
+        elif cfg.mlp_kind == "relu2":
+            h = jnp.square(jax.nn.relu(h))
+        else:
+            h = jax.nn.gelu(h)
+        ys = rd(h.astype(cd), "w_out")
+        yk = jnp.take(ys, jnp.argsort(order), axis=0).reshape(b * s, k, d)
+        y = jnp.einsum("nkd,nk->nd", yk, w).astype(cd)
+    if "shared" in p:
+        y = y + L.mlp_apply(p["shared"], xt, cfg.mlp_kind, compute_dtype=cd)
+    return y.reshape(b, s, d).astype(x.dtype), ids.reshape(b, s, k)

@@ -32,7 +32,7 @@ from . import mamba2 as S
 # Init
 # ---------------------------------------------------------------------------
 
-def _block_init(cfg, key):
+def _block_init(cfg, key, dense=False):
     ks = jax.random.split(key, 4)
     dt = cfg.pdtype
     if cfg.family == "ssm":
@@ -41,17 +41,24 @@ def _block_init(cfg, key):
     if cfg.family == "hybrid":
         return {"ln": L.rmsnorm_init(cfg.d_model, dt),
                 "mamba": S.mamba_init(ks[0], cfg, dt)}
-    p = {"ln1": L.rmsnorm_init(cfg.d_model, dt),
-         "attn": A.attn_init(ks[0], cfg.d_model, cfg.num_heads, cfg.num_kv_heads,
-                             cfg.head_dim, qkv_bias=cfg.qkv_bias, dtype=dt),
+    attn = A.mla_init(ks[0], cfg, dt) if cfg.uses_mla else A.attn_init(
+        ks[0], cfg.d_model, cfg.num_heads, cfg.num_kv_heads, cfg.head_dim,
+        qkv_bias=cfg.qkv_bias, dtype=dt)
+    p = {"ln1": L.rmsnorm_init(cfg.d_model, dt), "attn": attn,
          "ln2": L.rmsnorm_init(cfg.d_model, dt)}
-    if cfg.family == "moe":
-        p["moe"] = M.moe_init(ks[1], cfg.d_model, cfg.d_ff, cfg.num_experts,
-                              cfg.mlp_kind, dt)
+    if cfg.family == "moe" and not dense:
+        p["moe"] = M.moe_init(ks[1], cfg.d_model, cfg.expert_d_ff, cfg.num_experts,
+                              cfg.mlp_kind, dt, n_shared=cfg.n_shared_experts,
+                              dropless=cfg.moe_dropless)
     else:
         p["mlp"] = L.mlp_init(ks[1], cfg.d_model, cfg.d_ff, cfg.mlp_kind,
                               rank=cfg.lsq_rank, dtype=dt)
     return p
+
+
+def _n_dense(cfg) -> int:
+    """Leading dense layers ahead of the MoE stack (``params["dense"]``)."""
+    return cfg.first_k_dense_replace if cfg.family == "moe" else 0
 
 
 def init(cfg, key) -> dict[str, Any]:
@@ -59,8 +66,12 @@ def init(cfg, key) -> dict[str, Any]:
     p: dict[str, Any] = {}
     if cfg.family != "audio":
         p["embed"] = L.embed_init(ks[0], cfg.vocab_size, cfg.d_model, cfg.pdtype)
-    # stacked per-layer params
-    layer_keys = jax.random.split(ks[1], cfg.num_layers)
+    # stacked per-layer params: the leading dense layers, then the rest
+    kd = _n_dense(cfg)
+    if kd:
+        p["dense"] = jax.vmap(lambda k: _block_init(cfg, k, dense=True))(
+            jax.random.split(ks[5], kd))
+    layer_keys = jax.random.split(ks[1], cfg.num_layers - kd)
     p["blocks"] = jax.vmap(lambda k: _block_init(cfg, k))(layer_keys)
     if cfg.family == "hybrid":
         p["shared"] = {
@@ -85,20 +96,37 @@ def init(cfg, key) -> dict[str, Any]:
 # Blocks (full-sequence)
 # ---------------------------------------------------------------------------
 
-def _attn_block(cfg, bp, x, positions, *, window=None, emit_cache=False):
-    h, kv = A.attn_apply(bp["attn"], L.rmsnorm_apply(bp["ln1"], x, cfg.norm_eps),
-                         positions, cfg, causal=cfg.causal, window=window,
-                         compute_dtype=cfg.cdtype)
-    x = x + h
-    y = L.rmsnorm_apply(bp["ln2"], x, cfg.norm_eps)
-    if cfg.family == "moe":
-        m, aux = M.moe_apply(bp["moe"], y, top_k=cfg.top_k,
-                             capacity_factor=cfg.capacity_factor,
-                             kind=cfg.mlp_kind, compute_dtype=cfg.cdtype)
+def _ffn(cfg, bp, y, *, capacity_factor=None, experts=None, layer=None):
+    """The block's MLP half: dense MLP, dropless sigmoid MoE (its routed
+    experts the stack ``experts`` at ``layer``, see ``moe.split_experts``)
+    or softmax MoE with a capacity (``cfg.capacity_factor`` unless given).
+    -> (out, aux, expert ids (B, S, k) or None)."""
+    zeros = {"aux_loss": jnp.zeros(()), "router_z_loss": jnp.zeros(())}
+    if "moe" not in bp:
+        return L.mlp_apply(bp["mlp"], y, cfg.mlp_kind, compute_dtype=cfg.cdtype), zeros, None
+    if cfg.moe_dropless:
+        m, ids = M.moe_dropless(bp["moe"], y, cfg, experts, layer,
+                                compute_dtype=cfg.cdtype)
+        return m, zeros, ids
+    m, aux = M.moe_apply(bp["moe"], y, top_k=cfg.top_k,
+                         capacity_factor=capacity_factor or cfg.capacity_factor,
+                         kind=cfg.mlp_kind, compute_dtype=cfg.cdtype)
+    return m, aux, None
+
+
+def _attn_block(cfg, bp, x, positions, *, window=None, emit_cache=False,
+                experts=None, layer=None):
+    """-> (x, aux, the layer's cache entry (k, v) or MLA latent, expert ids)."""
+    h = L.rmsnorm_apply(bp["ln1"], x, cfg.norm_eps)
+    if cfg.uses_mla:
+        h, kv = A.mla_apply(bp["attn"], h, positions, cfg, compute_dtype=cfg.cdtype)
     else:
-        m = L.mlp_apply(bp["mlp"], y, cfg.mlp_kind, compute_dtype=cfg.cdtype)
-        aux = {"aux_loss": jnp.zeros(()), "router_z_loss": jnp.zeros(())}
-    return x + m, aux, (kv if emit_cache else None)
+        h, kv = A.attn_apply(bp["attn"], h, positions, cfg, causal=cfg.causal,
+                             window=window, compute_dtype=cfg.cdtype)
+    x = x + h
+    m, aux, ids = _ffn(cfg, bp, L.rmsnorm_apply(bp["ln2"], x, cfg.norm_eps),
+                       experts=experts, layer=layer)
+    return x + m, aux, (kv if emit_cache else None), ids
 
 
 def _mamba_block(cfg, bp, x):
@@ -327,14 +355,28 @@ def _stacked_forward(cfg, params, x, positions, *, window=None, mesh=None,
         return _hybrid_forward(cfg, params, x, positions, window=window,
                                mesh=mesh, seq_parallel=seq_parallel)
 
-    def body(carry, bp):
-        x, aux = carry
-        y, a, kv = _attn_block(cfg, bp, x, positions, window=window,
-                               emit_cache=True)
-        aux = {k: aux[k] + a[k] for k in aux}
-        return (y, aux), kv
-    body = jax.checkpoint(body) if cfg.remat else body
-    (x, aux), kvs = jax.lax.scan(body, (x, aux0), params["blocks"])
+    def run(x, aux, blocks):
+        blocks, experts = M.split_experts(cfg, blocks)
+
+        def body(carry, xs):
+            x, aux = carry
+            bp, li = xs
+            y, a, kv, ids = _attn_block(cfg, bp, x, positions, window=window,
+                                        emit_cache=True, experts=experts, layer=li)
+            aux = {k: aux[k] + a[k] for k in aux}
+            return (y, aux), (kv, ids)
+        body = jax.checkpoint(body) if cfg.remat else body
+        n = jax.tree.leaves(blocks)[0].shape[0]
+        return jax.lax.scan(body, (x, aux), (blocks, jnp.arange(n)))
+
+    kvs = []
+    if "dense" in params:
+        (x, aux0), (kv, _) = run(x, aux0, params["dense"])
+        kvs.append(kv)
+    (x, aux), (kv, routes) = run(x, aux0, params["blocks"])
+    kvs = jax.tree.map(lambda *a: jnp.concatenate(a, 0), *kvs, kv)
+    if cfg.uses_mla:
+        return x, aux, {"lat": kvs, "routes": routes}
     return x, aux, {"k": kvs[0], "v": kvs[1]}
 
 
@@ -445,7 +487,9 @@ def train_loss(cfg, params, batch, mesh=None, seq_parallel=False):
 def init_cache(cfg, batch_size: int, max_len: int, dtype=jnp.bfloat16):
     c: dict[str, Any] = {"len": jnp.zeros((), jnp.int32)}
     Lr = cfg.num_layers
-    if cfg.family in ("dense", "moe", "vlm", "audio"):
+    if cfg.uses_mla:          # the latent row [c_kv | k_pe] of each position
+        c.update(_latent_cache(cfg, Lr, batch_size, max_len, dtype))
+    elif cfg.family in ("dense", "moe", "vlm", "audio"):
         c["k"] = jnp.zeros((Lr, batch_size, max_len, cfg.num_kv_heads, cfg.head_dim), dtype)
         c["v"] = jnp.zeros_like(c["k"])
     elif cfg.family == "ssm":
@@ -459,6 +503,36 @@ def init_cache(cfg, batch_size: int, max_len: int, dtype=jnp.bfloat16):
         c["conv"] = _conv_cache(cfg, Lr, batch_size, dtype)
         c["k"] = jnp.zeros((n_full, batch_size, max_len, cfg.num_kv_heads, cfg.head_dim), dtype)
         c["v"] = jnp.zeros_like(c["k"])
+    return c
+
+
+def _latent_cache(cfg, Lr, batch_size, max_len, dtype):
+    """MLA's cache: c_kv in ``ckv`` (L, B, T, r) and k_pe in ``kpe``
+    (L, B, T/2, 2 rope), row u holding position u in its first half and
+    u + T/2 in its second — r + rope values a position, with neither
+    array's minor dimension padded to the chip's 128 lanes (a 576-wide
+    row would be, or would be laid out positions-minor, which an in-place
+    row write has to copy whole)."""
+    if max_len % 2:
+        raise ValueError(f"an MLA cache needs an even max_len, got {max_len}")
+    r, rope = cfg.kv_lora_rank, cfg.qk_rope_head_dim
+    return {"ckv": jnp.zeros((Lr, batch_size, max_len, r), dtype),
+            "kpe": jnp.zeros((Lr, batch_size, max_len // 2, 2 * rope), dtype)}
+
+
+def _store_latent(cache, lat, slot):
+    """Write the forward's latent rows ``lat`` (L, b, n, r + rope) of
+    positions 0..n-1 into batch rows slot..slot+b-1 of ``cache``."""
+    r, h = cache["ckv"].shape[-1], cache["kpe"].shape[2]
+    n, dt = lat.shape[2], cache["ckv"].dtype
+    c = dict(cache)
+    c["ckv"] = jax.lax.dynamic_update_slice(cache["ckv"], lat[..., :r].astype(dt),
+                                            (0, slot, 0, 0))
+    kp = lat[..., r:].astype(dt)
+    c["kpe"] = jax.lax.dynamic_update_slice(cache["kpe"], kp[:, :, :h], (0, slot, 0, 0))
+    if n > h:
+        c["kpe"] = jax.lax.dynamic_update_slice(c["kpe"], kp[:, :, h:],
+                                                (0, slot, 0, kp.shape[-1]))
     return c
 
 
@@ -482,6 +556,8 @@ def prefill(cfg, params, batch, max_len: int | None = None, *, window=None,
         s += batch["patch_embeds"].shape[1]
     max_len = max_len or s
     cache = init_cache(cfg, b, max_len, dtype=cfg.cdtype)
+    if caches.get("lat") is not None:
+        cache = _store_latent(cache, caches["lat"], 0)
     if caches.get("k") is not None:
         cache["k"] = jax.lax.dynamic_update_slice(
             cache["k"], caches["k"].astype(cache["k"].dtype), (0, 0, 0, 0, 0))
@@ -503,7 +579,13 @@ def decode_step(cfg, params, cache, tokens, *, window=None, mesh=None,
     x = L.embed_apply(params["embed"], tokens, cfg.cdtype)
     clen = cache["len"]
 
-    if cfg.family in ("dense", "moe", "vlm"):
+    if cfg.uses_mla:
+        b = tokens.shape[0]
+        x, cache, _ = _mla_decode(cfg, params, cache, x,
+                                  jnp.full((b,), clen, jnp.int32), jnp.ones((b,), bool))
+        cache = dict(cache, len=clen + 1)
+
+    elif cfg.family in ("dense", "moe", "vlm"):
         def body(x, xs):
             bp, ck, cv = xs
             h = L.rmsnorm_apply(bp["ln1"], x, cfg.norm_eps)
@@ -576,11 +658,7 @@ def decode_step(cfg, params, cache, tokens, *, window=None, mesh=None,
         raise ValueError(f"no decode path for family {cfg.family!r}")
 
     x = L.rmsnorm_apply(params["final_norm"], x, cfg.norm_eps)
-    if not cfg.tie_embeddings and "lm_head" in params:
-        logits = L.dense_apply(params["lm_head"], x, compute_dtype=cfg.cdtype).astype(jnp.float32)
-    else:
-        logits = L.unembed_apply(params["embed"], x, cfg.cdtype)
-    return logits, cache
+    return _head(cfg, params, x), cache
 
 
 # ---------------------------------------------------------------------------
@@ -606,7 +684,7 @@ def reset_cache_slot(cfg, cache, slot: int):
     can never leak past an off-by-one in the position mask)."""
     c = dict(cache)
     c["pos"] = cache["pos"].at[slot].set(0)
-    for name in ("k", "v", "ssm"):
+    for name in ("k", "v", "ssm", "ckv", "kpe"):
         if cache.get(name) is not None:
             c[name] = cache[name].at[:, slot].set(0)
     if cache.get("conv") is not None:
@@ -614,36 +692,34 @@ def reset_cache_slot(cfg, cache, slot: int):
     return c
 
 
-def prefill_into_slot(cfg, params, cache, batch, slot, *, window=None,
-                      return_hidden=False):
+def prefill_into_slot(cfg, params, cache, batch, slot, *, length=None,
+                      window=None, return_hidden=False, return_routes=False):
     """Prefill ONE sequence (leading batch dim 1) and write its caches into
     row ``slot`` of a slotted cache — the admission half of continuous
     batching: a freed slot is re-prefilled without touching the other
     residents.  ``slot`` may be a traced scalar, so the whole function jits
-    once per prompt length.  Returns ``(logits (1, s, V) f32, new cache)``
-    — or the final normed hidden states ``(1, s, D)`` with
-    ``return_hidden=True`` (quantized-head serving applies its own head)."""
-    x, _, caches, off = backbone(cfg, params, batch, window=window)
-    if return_hidden:
-        out = x
-    else:
-        if not cfg.tie_embeddings and "lm_head" in params:
-            out = L.dense_apply(params["lm_head"], x,
-                                compute_dtype=cfg.cdtype).astype(jnp.float32)
-        else:
-            out = L.unembed_apply(params["embed"], x, cfg.cdtype)
-        if cfg.family == "vlm" and off:
-            out = out[:, off:]
+    once per prompt length.  ``length`` (traced, optional): the prompt's
+    real length when ``batch`` is padded at its end to a length bucket; the
+    slot's ``pos`` is set to it, and what lies past it is never attended.
+    Returns ``(logits (1, 1, V) f32 of the last real position, new cache)``
+    — the head runs at that position only — or its final normed hidden
+    state ``(1, 1, D)`` with ``return_hidden=True`` (quantized-head serving
+    applies its own head).  ``return_routes`` adds the chosen experts of
+    every position at each MoE layer, ``(1, s, n_moe, k)`` (MLA MoE)."""
+    x, _, caches, _ = backbone(cfg, params, batch, window=window)
     s = x.shape[1]                       # includes vlm patch positions
+    n = jnp.asarray(s if length is None else length, jnp.int32)
+    x = jax.lax.dynamic_slice_in_dim(x, n - 1, 1, axis=1)
+    out = x if return_hidden else _head(cfg, params, x)
     slot = jnp.asarray(slot, jnp.int32)
     c = dict(cache)
-    if caches.get("k") is not None:
-        c["k"] = jax.lax.dynamic_update_slice(
-            cache["k"], caches["k"].astype(cache["k"].dtype),
-            (0, slot, 0, 0, 0))
-        c["v"] = jax.lax.dynamic_update_slice(
-            cache["v"], caches["v"].astype(cache["v"].dtype),
-            (0, slot, 0, 0, 0))
+    for name in ("k", "v"):
+        if caches.get(name) is not None:
+            new = caches[name].astype(cache[name].dtype)
+            c[name] = jax.lax.dynamic_update_slice(
+                cache[name], new, (0, slot) + (0,) * (new.ndim - 2))
+    if caches.get("lat") is not None:
+        c = _store_latent(c, caches["lat"], slot)
     if caches.get("ssm") is not None:
         c["ssm"] = jax.lax.dynamic_update_slice(
             cache["ssm"], caches["ssm"].astype(cache["ssm"].dtype),
@@ -652,16 +728,78 @@ def prefill_into_slot(cfg, params, cache, batch, slot, *, window=None,
             lambda dst, src: jax.lax.dynamic_update_slice(
                 dst, src.astype(dst.dtype), (0, slot, 0, 0)),
             cache["conv"], caches["conv"])
-    c["pos"] = jax.lax.dynamic_update_slice(
-        cache["pos"], jnp.asarray([s], jnp.int32), (slot,))
+    c["pos"] = jax.lax.dynamic_update_slice(cache["pos"], n[None], (slot,))
+    if return_routes:
+        routes = caches.get("routes")
+        return out, c, None if routes is None else jnp.moveaxis(routes, 0, 2)
     return out, c
 
 
+def _head(cfg, params, x):
+    """Final normed hidden states -> f32 logits (untied head or tied embed)."""
+    if not cfg.tie_embeddings and "lm_head" in params:
+        return L.dense_apply(params["lm_head"], x,
+                             compute_dtype=cfg.cdtype).astype(jnp.float32)
+    return L.unembed_apply(params["embed"], x, cfg.cdtype)
+
+
+def _mla_decode(cfg, params, cache, x, pos, active):
+    """One token per row through the dense layers and the scanned MoE stack
+    over the latent cache (``_latent_cache``).  Each active row writes its
+    new latent row in place at ``pos[b]`` (inactive rows write nothing),
+    then attends over 0..pos[b].  The cache rides in the scan's carry, so
+    under donation the device holds one cache.  -> (x, cache, expert ids
+    (n_moe, B, 1, k) or None)."""
+    cd, r, kd = cfg.cdtype, cfg.kv_lora_rank, _n_dense(cfg)
+    rows = jnp.arange(x.shape[0])
+    wpos = jnp.where(active, pos, cache["ckv"].shape[2])     # past the end: dropped
+    h = cache["kpe"].shape[2]
+
+    def layer(x, ckv, kpe, bp, li, experts):
+        y = L.rmsnorm_apply(bp["ln1"], x, cfg.norm_eps)
+        q_nope, q_pe, new = A.mla_qkv(bp["attn"], y, pos[:, None], cfg, compute_dtype=cd)
+        new = new[:, 0].astype(ckv.dtype)
+        ckv = ckv.at[li, rows, wpos].set(new[:, :r], mode="drop", unique_indices=True)
+        # k_pe lands in its row's half: row wpos % h, lanes from (wpos // h) rope
+        at = jnp.stack([jnp.full_like(rows, li), rows, wpos % h,
+                        (wpos // h) * cfg.qk_rope_head_dim], axis=-1)
+        kpe = jax.lax.scatter(kpe, at, new[:, r:], jax.lax.ScatterDimensionNumbers(
+            update_window_dims=(1,), inserted_window_dims=(0, 1, 2),
+            scatter_dims_to_operand_dims=(0, 1, 2, 3)),
+            unique_indices=True, mode=jax.lax.GatherScatterMode.FILL_OR_DROP)
+        x = x + A.mla_decode_attend(bp["attn"], q_nope, q_pe, ckv, kpe, li, pos, cfg,
+                                    compute_dtype=cd)
+        m, _, ids = _ffn(cfg, bp, L.rmsnorm_apply(bp["ln2"], x, cfg.norm_eps),
+                         capacity_factor=cfg.num_experts / max(cfg.top_k, 1),
+                         experts=experts, layer=li - kd)
+        return x + m, ckv, kpe, ids
+
+    def scan(carry, blocks, first):
+        blocks, experts = M.split_experts(cfg, blocks)
+
+        def body(carry, xs):
+            x, ckv, kpe = carry
+            bp, li = xs
+            x, ckv, kpe, ids = layer(x, ckv, kpe, bp, li, experts)
+            return (x, ckv, kpe), ids
+        n = jax.tree.leaves(blocks)[0].shape[0]
+        return jax.lax.scan(body, carry, (blocks, first + jnp.arange(n)))
+
+    carry = (x, cache["ckv"], cache["kpe"])
+    if kd:
+        carry, _ = scan(carry, params["dense"], 0)
+    carry, ids = scan(carry, params["blocks"], kd)
+    x, ckv, kpe = carry
+    return x, dict(cache, ckv=ckv, kpe=kpe), ids
+
+
 def decode_step_slotted(cfg, params, cache, tokens, active=None, *,
-                        window=None, return_hidden=False):
+                        window=None, return_hidden=False, return_routes=False):
     """One decode tick over a slotted cache.  tokens: (S, 1) int32 ->
     ``(logits (S, 1, V) f32, new cache)`` — or final normed hidden states
-    ``(S, 1, D)`` with ``return_hidden=True``.
+    ``(S, 1, D)`` with ``return_hidden=True``.  ``return_routes`` adds each
+    row's chosen experts at every MoE layer, ``(S, n_moe, k)`` (MLA MoE;
+    else None).
 
     Unlike :func:`decode_step`, every slot advances at its own
     ``cache["pos"][b]``: row ``b`` writes its K/V (or SSM update) at its
@@ -674,8 +812,13 @@ def decode_step_slotted(cfg, params, cache, tokens, active=None, *,
     if active is None:
         active = jnp.ones((tokens.shape[0],), bool)
     active = active.astype(bool)
+    routes = None
 
-    if cfg.family in ("dense", "moe", "vlm"):
+    if cfg.uses_mla:
+        x, cache, ids = _mla_decode(cfg, params, cache, x, pos, active)
+        routes = None if ids is None else jnp.moveaxis(ids[:, :, 0], 0, 1)
+
+    elif cfg.family in ("dense", "moe", "vlm"):
         def body(x, xs):
             bp, ck, cv = xs
             h = L.rmsnorm_apply(bp["ln1"], x, cfg.norm_eps)
@@ -756,11 +899,5 @@ def decode_step_slotted(cfg, params, cache, tokens, active=None, *,
 
     cache["pos"] = pos + active.astype(jnp.int32)
     x = L.rmsnorm_apply(params["final_norm"], x, cfg.norm_eps)
-    if return_hidden:
-        return x, cache
-    if not cfg.tie_embeddings and "lm_head" in params:
-        logits = L.dense_apply(params["lm_head"], x,
-                               compute_dtype=cfg.cdtype).astype(jnp.float32)
-    else:
-        logits = L.unembed_apply(params["embed"], x, cfg.cdtype)
-    return logits, cache
+    out = x if return_hidden else _head(cfg, params, x)
+    return (out, cache, routes) if return_routes else (out, cache)

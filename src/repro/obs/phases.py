@@ -30,7 +30,7 @@ FLEET_PHASES = (
 )
 
 #: Continuous-batching LM engine phases (serve/engine.py).
-LM_PHASES = ("lm.tick", "lm.prefill", "lm.decode")
+LM_PHASES = ("lm.tick", "lm.prefill", "lm.decode", "lm.sample")
 
 #: Slot-scheduler phases (serve/scheduler.py).
 SCHED_PHASES = ("sched.admit", "sched.release")

@@ -22,7 +22,8 @@ def _setup(arch):
 
 
 @pytest.mark.parametrize("arch", ["deepseek-7b", "olmoe-1b-7b",
-                                  "mamba2-780m", "zamba2-1.2b"])
+                                  "moonlight-16b-a3b", "mamba2-780m",
+                                  "zamba2-1.2b"])
 def test_decode_matches_forward(arch):
     cfg, params, toks = _setup(arch)
     full, _, _ = T.forward(cfg, params, {"tokens": jnp.asarray(toks)})
@@ -34,7 +35,8 @@ def test_decode_matches_forward(arch):
         assert err < 1e-4, (arch, t, err)
 
 
-@pytest.mark.parametrize("arch", ["deepseek-7b", "mamba2-780m", "zamba2-1.2b"])
+@pytest.mark.parametrize("arch", ["deepseek-7b", "moonlight-16b-a3b",
+                                  "mamba2-780m", "zamba2-1.2b"])
 def test_prefill_then_decode_continuous(arch):
     cfg, params, toks = _setup(arch)
     full, _, _ = T.forward(cfg, params, {"tokens": jnp.asarray(toks)})

@@ -72,10 +72,7 @@ def test_full_config_parameter_counts_sane():
     expected = {  # billions, loose bands from the source papers
         "minitron-4b": (3.5, 5.5), "qwen2-1.5b": (1.2, 2.0),
         "deepseek-7b": (6.0, 8.0), "nemotron-4-340b": (300, 380),
-        # moonshot: the assigned config (48L, 64e x swiglu(1408) every
-        # layer) counts 28B; the shipping 16B model makes some layers
-        # dense/shared-expert, which the assignment spec does not encode.
-        "olmoe-1b-7b": (6.0, 8.0), "moonshot-v1-16b-a3b": (14, 30),
+        "olmoe-1b-7b": (6.0, 8.0), "moonlight-16b-a3b": (15.9, 16.0),
         "internvl2-76b": (65, 80), "zamba2-1.2b": (0.9, 1.6),
         "hubert-xlarge": (0.7, 1.3), "mamba2-780m": (0.6, 1.0),
     }

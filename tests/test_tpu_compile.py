@@ -99,6 +99,45 @@ def test_q15_matmul_compiles_at_lm_head_width(one_chip, compiled_path):
     assert "tpu_custom_call" in text
 
 
+def test_mla_decode_kernel_compiles(one_chip, compiled_path):
+    """Moonlight's absorbed decode attention at its benchmark size: 128
+    slots x 8,192 positions, 16 heads, kv_lora_rank 512, rope 64."""
+    from repro.kernels.mla_decode import mla_decode_attention
+    bf = lambda *shape: _sds(shape, jnp.bfloat16, one_chip)
+    i32 = lambda *shape: _sds(shape, jnp.int32, one_chip)
+    fn = jax.jit(lambda ql, qp, c, p, li, pos: mla_decode_attention(
+        ql, qp, c, p, li, pos, scale=192 ** -0.5))
+    text = fn.lower(bf(128, 16, 512), bf(128, 16, 64), bf(5, 128, 8192, 512),
+                    bf(5, 128, 4096, 128), i32(), i32(128)).compile().as_text()
+    assert "tpu_custom_call" in text
+    assert "%mla_decode" in text
+
+
+def test_moonlight_decode_step_holds_one_cache(one_chip, compiled_path):
+    """The slotted decode step at the benchmark's size, on the chip's
+    row-major layouts: the donated latent cache is written in place (no
+    second cache in temporaries) and the step fits beside the weights."""
+    import dataclasses
+    from jax.experimental.layout import Format, Layout
+    import repro.configs as C
+    from repro.models import transformer as T
+    cfg = dataclasses.replace(C.get("moonlight-16b-a3b"), num_layers=5)
+    fmt = lambda a: Format(Layout(tuple(range(a.ndim))), one_chip)
+    sds = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=fmt(a))
+    params = jax.eval_shape(lambda k: T.init(cfg, k), jax.random.PRNGKey(0))
+    cache = jax.eval_shape(lambda: T.init_slot_cache(cfg, 128, 8192))
+    args = jax.tree.map(sds, (params, cache, jax.ShapeDtypeStruct((128, 1), jnp.int32),
+                              jax.ShapeDtypeStruct((128,), jnp.bool_)))
+    step = lambda p, c, t, a: T.decode_step_slotted(cfg, p, c, t, a, return_routes=True)
+    outs = jax.tree.map(fmt, jax.eval_shape(step, *args))
+    mem = jax.jit(step, donate_argnums=(1,), out_shardings=outs).lower(
+        *args).compile().memory_analysis()
+    cache_bytes = 5 * 128 * 8192 * 576 * 2
+    assert mem.alias_size_in_bytes >= cache_bytes
+    assert mem.temp_size_in_bytes < 1e9
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 14e9
+
+
 def _chip_smoke():
     spec = importlib.util.spec_from_file_location(
         "chip_smoke", os.path.join(REPO, "chip_smoke.py"))
