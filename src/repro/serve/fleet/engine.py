@@ -222,7 +222,7 @@ class FleetEngine:
         # write the fused x operand in place (zero concat), and the fused
         # step's output h is adopted back as next tick's input when no
         # shard rebound its hidden state in between.
-        d = self.shards[0].kernel.input_dim
+        d = self._input_dim = self.shards[0].kernel.input_dim
         self._group_list: list[_DeviceGroup] = []
         self._group_of: dict[int, _DeviceGroup] = {}
         for dev, idxs in groups:
@@ -401,7 +401,7 @@ class FleetEngine:
         if stream_id in self._owner or stream_id in self._spilled:
             raise ValueError(f"stream {stream_id!r} already attached")
         coerced = (None if samples is None
-                   else self._check_samples(stream_id, samples))
+                   else coerce_samples(samples, self._input_dim, stream_id))
         if self.config.snapshot_every is not None:
             self._drop_failover_state(stream_id)   # reused finished id
             self._journal[stream_id] = _JournalEntry(
@@ -429,15 +429,18 @@ class FleetEngine:
         tok = tr.open_count("fleet.feed")
         try:
             shard = self._owner.get(stream_id)
-            if shard is not None and stream_id in self.shards[shard]._sessions:
-                coerced = self._check_samples(stream_id, samples)
+            s = (None if shard is None
+                 else self.shards[shard]._sessions.get(stream_id))
+            if s is not None:
+                coerced = coerce_samples(samples, self._input_dim, stream_id)
                 self._journal_feed(stream_id, coerced)
-                self.shards[shard].feed(stream_id, coerced)
+                self.shards[shard]._feed_coerced(s, coerced)
                 return
-            if stream_id in self._spilled:
-                coerced = self._check_samples(stream_id, samples)
+            entry = self._spilled.get(stream_id)
+            if entry is not None:
+                coerced = coerce_samples(samples, self._input_dim, stream_id)
                 self._journal_feed(stream_id, coerced)
-                self._spilled[stream_id].chunks.append(coerced)
+                entry.chunks.append(coerced)
                 return
             raise KeyError(f"stream {stream_id!r} is not attached")
         finally:
@@ -1163,10 +1166,6 @@ class FleetEngine:
     # ------------------------------------------------------------------
     # Internals
     # ------------------------------------------------------------------
-    def _check_samples(self, stream_id: str, samples) -> np.ndarray:
-        return coerce_samples(samples, self.shards[0].kernel.input_dim,
-                              stream_id)
-
     def _shard_has_room(self, i: int) -> bool:
         if not self._routable[i]:
             return False

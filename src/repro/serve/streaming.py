@@ -249,6 +249,7 @@ class StreamingEngine:
                 raise ValueError("device_resident=True requires the jit or "
                                  "pallas backend (exact is host NumPy)")
         S, d = config.max_slots, self.kernel.input_dim
+        self._input_dim = d
         self._h = (self.kernel.init_state_device(S) if self._device_resident
                    else self.kernel.init_state(S))
         self._h_inflight = False  # a step_resident dispatch is in flight:
@@ -357,7 +358,13 @@ class StreamingEngine:
     def feed(self, stream_id: str, samples: np.ndarray) -> None:
         """Append samples ((d,) or (k, d)) to a stream's input buffer."""
         s = self._sessions[stream_id]
-        samples = coerce_samples(samples, self.kernel.input_dim, stream_id)
+        self._feed_coerced(
+            s, coerce_samples(samples, self._input_dim, stream_id))
+
+    def _feed_coerced(self, s: _Session, samples: np.ndarray) -> None:
+        """:meth:`feed` for samples that are already canonical (k, d)
+        float32: the fleet front validates each packet once, in its own
+        ``feed``, and hands the session it looked up."""
         if s.slot < 0:
             s.chunks.append(samples)
         else:
@@ -956,15 +963,12 @@ class StreamingEngine:
         if slot in self._spill:          # keep FIFO order behind the spill
             self._spill[slot].append(samples)
             return
-        needed = int(self._tail[slot] - self._head[slot]) + k
-        if needed > self._cap and self._cap < self.config.max_ring_capacity:
-            self._grow_ring(min(needed, self.config.max_ring_capacity))
-        space = self._cap - int(self._tail[slot] - self._head[slot])
-        take = min(space, k)
-        if take:
-            idx = (self._tail[slot] + np.arange(take)) % self._cap
-            self._ring[idx, slot] = samples[:take]
-            self._tail[slot] += take
+        max_cap = self.config.max_ring_capacity
+        if self._cap < max_cap:
+            needed = self._tail.item(slot) - self._head.item(slot) + k
+            if needed > self._cap:
+                self._grow_ring(min(needed, max_cap))
+        take = self._ring_append(slot, samples)
         if take < k:                     # backlog beyond the shared ring
             self._spill[slot] = collections.deque([samples[take:]])
             self._ring_spills += 1
@@ -975,19 +979,31 @@ class StreamingEngine:
         for slot in list(self._spill):
             q = self._spill[slot]
             while q:
-                space = self._cap - int(self._tail[slot] - self._head[slot])
-                if space <= 0:
-                    break
                 chunk = q.popleft()
-                take = min(space, len(chunk))
-                idx = (self._tail[slot] + np.arange(take)) % self._cap
-                self._ring[idx, slot] = chunk[:take]
-                self._tail[slot] += take
+                take = self._ring_append(slot, chunk)
                 if take < len(chunk):
                     q.appendleft(chunk[take:])
                     break
             if not q:
                 del self._spill[slot]
+
+    def _ring_append(self, slot: int, samples: np.ndarray) -> int:
+        """Append as many of ``samples`` as the slot's ring has room for and
+        return that count.  The write is at most two contiguous slices, the
+        second only where it wraps past the ring's end; the cursors are
+        read as Python ints, so no per-sample index array is built."""
+        cap = self._cap
+        tail = self._tail.item(slot)
+        take = min(cap - (tail - self._head.item(slot)), len(samples))
+        if take <= 0:
+            return 0
+        o = tail % cap
+        n1 = min(take, cap - o)
+        self._ring[o:o + n1, slot] = samples[:n1]
+        if n1 < take:
+            self._ring[:take - n1, slot] = samples[n1:take]
+        self._tail[slot] = tail + take
+        return take
 
     def _grow_ring(self, needed: int) -> None:
         new_cap = self._cap

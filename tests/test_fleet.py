@@ -10,6 +10,7 @@ forced mid-stream migrations.  That is what makes the fleet a serving
 core rather than a demo."""
 import dataclasses
 import random
+import re
 
 import jax
 import numpy as np
@@ -210,6 +211,90 @@ def test_feed_and_detach_on_spilled_stream(qp, windows):
     with pytest.raises(KeyError):
         fleet.feed("late", windows[2])
     fleet.attach("late", windows[2], total_steps=128)  # id reusable
+
+
+# each form a caller may hand ``feed``, as the calls that feed one packet
+_SAMPLE_FORMS = {
+    "row": lambda p: list(p),                       # (d,) a call
+    "lists": lambda p: [p.tolist()],
+    "float64": lambda p: [p.astype(np.float64)],
+    "int": lambda p: [p.astype(np.int64)],
+    "canonical": lambda p: [p],                     # float32 (k, d)
+}
+
+
+def _three_state_fleet(qp):
+    """A journaled fleet of one one-slot shard: the first stream attached is
+    resident, the second pending, the third in the fleet's spill queue."""
+    return FleetEngine(qp, FleetConfig(
+        shards=1, stream=StreamingConfig(max_slots=1),
+        max_pending_per_shard=1, snapshot_every=10_000))
+
+
+@pytest.mark.parametrize("form", list(_SAMPLE_FORMS))
+def test_feed_accepts_every_sample_form_alike(qp, windows, form):
+    """Every form of the same samples lands as the same float32 rows in a
+    resident stream's ring, a pending stream's chunks, a spilled stream's
+    queue and each stream's replay journal, and gives the same logits."""
+    sids = ("res", "pend", "spill")
+    data = {sid: np.rint(windows[i] * 4).astype(np.float32)   # int-valued
+            for i, sid in enumerate(sids)}
+    sizes = [1, 5, 25, 33, 64]
+    bounds = np.cumsum([0] + sizes)
+    fleet = _three_state_fleet(qp)
+    assert [fleet.attach(sid, total_steps=128) for sid in sids] == \
+        ["active", "pending", "spilled"]
+    for sid in sids:
+        for a, b in zip(bounds[:-1], bounds[1:]):
+            for x in _SAMPLE_FORMS[form](data[sid][a:b]):
+                fleet.feed(sid, x)
+    eng = fleet.shards[0]
+    np.testing.assert_array_equal(
+        eng._ring[:128, eng._sessions["res"].slot], data["res"])
+    np.testing.assert_array_equal(
+        np.concatenate(eng._sessions["pend"].chunks), data["pend"])
+    np.testing.assert_array_equal(
+        np.concatenate(fleet._spilled["spill"].chunks), data["spill"])
+    for sid in sids:
+        chunks = fleet._journal[sid].chunks
+        assert all(c.dtype == np.float32 and c.ndim == 2 for c in chunks)
+        assert [len(c) for c in chunks] == \
+            ([1] * 128 if form == "row" else sizes)
+        np.testing.assert_array_equal(np.concatenate(chunks), data[sid])
+    by_id = _collect(fleet.drain())
+    rt = QRuntime(qp)
+    for sid in sids:
+        np.testing.assert_array_equal(
+            by_id[sid].logits.view(np.int32),
+            rt.run_window(data[sid]).view(np.int32))
+
+
+@pytest.mark.parametrize("bad", ["wrong-width", "3-d"])
+def test_feed_rejects_bad_shapes_naming_the_stream(qp, windows, bad):
+    """A wrong width or rank raises ValueError naming the stream, from the
+    fleet (resident, pending, spilled) and the single engine, and leaves
+    the stream's buffers and journal as they were."""
+    head = windows[0][:4]
+    d = head.shape[1]
+    x = (np.zeros((5, d + 1), np.float32) if bad == "wrong-width"
+         else np.zeros((1, 5, d), np.float32))
+    fleet = _three_state_fleet(qp)
+    for sid in ("res", "pend", "spill"):
+        fleet.attach(sid, head)
+        with pytest.raises(ValueError, match=re.escape(f"stream {sid!r}")):
+            fleet.feed(sid, x)
+        assert len(fleet._journal[sid].chunks) == 1
+    eng = fleet.shards[0]
+    for sid in ("res", "pend"):
+        np.testing.assert_array_equal(eng.snapshot_stream(sid).samples, head)
+    np.testing.assert_array_equal(
+        np.concatenate(fleet._spilled["spill"].chunks), head)
+    solo = StreamingEngine(qp, StreamingConfig(max_slots=1))
+    for sid in ("a", "b"):                  # resident, then pending
+        solo.attach(sid, head)
+        with pytest.raises(ValueError, match=re.escape(f"stream {sid!r}")):
+            solo.feed(sid, x)
+        np.testing.assert_array_equal(solo.snapshot_stream(sid).samples, head)
 
 
 def test_stream_id_reusable_after_completion(qp, windows):

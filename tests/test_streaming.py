@@ -237,6 +237,120 @@ def test_ring_growth_under_spill_pressure(qp, windows):
     assert eng.stats()["stream_steps"] == 384
 
 
+class _RingModel:
+    """Plain model of one slot's sample ring: each sample is written alone
+    at ``tail % cap``; a packet that does not fit grows the ring (doubling,
+    up to the cap) and re-bases the cursors, and what still does not fit
+    queues behind, in order, until a step frees room."""
+
+    def __init__(self, cap, max_cap):
+        self.cap, self.max_cap = max(8, min(cap, max_cap)), max_cap
+        self.cells = [None] * self.cap
+        self.head = self.tail = self.spills = 0
+        self.spill = []
+
+    def live(self):
+        return [self.cells[(self.head + i) % self.cap]
+                for i in range(self.tail - self.head)]
+
+    def feed(self, samples):
+        if self.spill:
+            self.spill.extend(samples)
+            return
+        need = self.tail - self.head + len(samples)
+        if need > self.cap and self.cap < self.max_cap:
+            cap = self.cap
+            while cap < need:
+                cap *= 2
+            live, self.cap = self.live(), min(cap, self.max_cap)
+            self.cells = live + [None] * (self.cap - len(live))
+            self.head, self.tail = 0, len(live)
+        rest = list(samples)
+        while rest and self.tail - self.head < self.cap:
+            self.cells[self.tail % self.cap] = rest.pop(0)
+            self.tail += 1
+        if rest:
+            self.spill, self.spills = rest, self.spills + 1
+
+    def step(self):
+        if self.tail > self.head:
+            self.head += 1
+        while self.spill and self.tail - self.head < self.cap:
+            self.cells[self.tail % self.cap] = self.spill.pop(0)
+            self.tail += 1
+
+
+@pytest.mark.parametrize("cap,max_cap,start,packets", [
+    (16, 16, 3, [1]),               # 1 sample, no wrap
+    (16, 16, 15, [1, 1]),           # 1 sample at the last cell, then wraps
+    (32, 32, 4, [25]),              # the fleet's packet, no wrap
+    (32, 32, 20, [25]),             # the fleet's packet across the end
+    (16, 16, 9, [15]),              # cap - 1, wraps
+    (16, 16, 0, [16]),              # cap at offset 0: fills the ring
+    (16, 16, 7, [16]),              # cap across the end
+    (16, 64, 7, [17]),              # cap + 1: the ring grows
+    (16, 16, 7, [17, 5]),           # cap + 1 at the cap: spill, FIFO behind
+    (8, 32, 5, [64, 3]),            # 2 * max_ring_capacity: grow, then spill
+], ids=["1", "1-wrap", "25", "25-wrap", "cap-1", "cap", "cap-wrap",
+        "cap+1-grow", "cap+1-spill", "2max-grow-spill"])
+def test_ring_write_matches_fifo_model(qp, cap, max_cap, start, packets):
+    """Every packet lands in the ring (and its spill) exactly as a
+    one-sample-at-a-time FIFO would put it, on three neighbouring slots,
+    through wraps, growth, spill and the drain that empties it."""
+    eng = StreamingEngine(qp, StreamingConfig(
+        max_slots=3, ring_capacity=cap, max_ring_capacity=max_cap))
+    d = eng.kernel.input_dim
+    sids = ["a", "b", "c"]
+    for sid in sids:
+        eng.attach(sid)
+    models = [_RingModel(cap, max_cap) for _ in sids]
+    fed = [[] for _ in sids]
+    consumed = 0
+
+    def feed(k):
+        for j, sid in enumerate(sids):    # distinct values per slot
+            n0 = len(fed[j])
+            x = (np.arange(n0 * d, (n0 + k) * d, dtype=np.float32)
+                 .reshape(k, d) + 1e5 * j)
+            eng.feed(sid, x)
+            models[j].feed(list(x))
+            fed[j] += list(x)
+
+    def step(n):
+        nonlocal consumed
+        for _ in range(n):
+            eng.step()
+            for m in models:
+                m.step()
+        consumed = min(consumed + n, len(fed[0]))
+
+    def check():
+        for j, sid in enumerate(sids):
+            slot, m = eng._sessions[sid].slot, models[j]
+            assert (eng._cap, eng._head[slot], eng._tail[slot]) == \
+                (m.cap, m.head, m.tail)
+            n = m.tail - m.head
+            ring = eng._ring[(m.head + np.arange(n)) % m.cap, slot]
+            want = np.array(fed[j][consumed:], np.float32).reshape(-1, d)
+            np.testing.assert_array_equal(ring, want[:n])
+            np.testing.assert_array_equal(
+                ring, np.array(m.live(), np.float32).reshape(-1, d))
+            np.testing.assert_array_equal(
+                eng.snapshot_stream(sid).samples, want)
+        assert eng.stats()["ring_spills"] == sum(m.spills for m in models)
+
+    feed(start)
+    step(start)
+    check()
+    for k in packets:
+        feed(k)
+        check()
+    while consumed < len(fed[0]):           # drain, spill included
+        step(min(7, len(fed[0]) - consumed))
+        check()
+    assert not eng._spill
+
+
 def test_drain_with_empty_pending_queue(qp, windows):
     eng = StreamingEngine(qp, StreamingConfig(max_slots=2))
     assert eng.drain() == []                 # nothing attached at all
