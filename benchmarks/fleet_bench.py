@@ -79,8 +79,7 @@ def _run(fleet: FleetEngine, n_streams: int,
     # steady-window transfer accounting: the ticks right after warm-up
     # are emission-free (the first window boundary is tick 128), so the
     # h-state byte deltas over this window are the device-residency
-    # gate — zero on the resident jit/pallas paths, a full h round
-    # trip per tick on the host-staged ones
+    # gate — zero on the resident jit/pallas paths
     steady = min(16, total - 2)
     tr0 = fleet.stats()["transfers"]
     tick_s = []

@@ -68,21 +68,25 @@ class Q15StreamStep:
     slot whose ``active`` flag is set by one sample; ``head_logits`` maps
     any subset of slot states to classifier logits (emission time only).
 
-    Backends (selected at construction):
+    Backends (selected at construction), one step path each:
 
-      * ``"exact"``  — vectorized NumPy.  Guaranteed bit-identical per
-        stream to the scalar ``core/qruntime.QRuntime`` reference: the
-        batched ops are the same scalar IEEE-754 f32 ops per row, and NumPy
-        never contracts mul+add into an FMA.  This is the agreement-contract
-        backend (paper contribution (i) at batch scale) and the CPU default.
-      * ``"jit"``    — the same qstep math jit-compiled with XLA.  Faster
-        per tick on accelerators, but XLA's CPU emitter contracts
-        ``a*b + c`` into FMAs (even through ``lax.optimization_barrier``),
-        so hidden states drift ~1e-9/step from the reference; argmax
-        predictions still agree in practice.
+      * ``"exact"``  — vectorized NumPy over a host h table.  Guaranteed
+        bit-identical per stream to the scalar ``core/qruntime.QRuntime``
+        reference: the batched ops are the same scalar IEEE-754 f32 ops per
+        row, and NumPy never contracts mul+add into an FMA.  This is the
+        agreement-contract backend (paper contribution (i) at batch scale)
+        and the CPU default.
+      * ``"jit"``    — the same qstep math jit-compiled with XLA.  XLA's
+        CPU emitter contracts ``a*b + c`` into FMAs (even through
+        ``lax.optimization_barrier``), so hidden states drift ~1e-9/step
+        from the reference; argmax predictions still agree in practice.
       * ``"pallas"`` — the ``kernel.make_fastgrnn_step`` Pallas kernel
         (compiled on a TPU, interpreted elsewhere), dequantizing the int16
         weights on use inside the kernel.
+
+    The device backends (jit, pallas) are resident (:attr:`resident`): h
+    lives on the device between ticks and :meth:`step_resident` advances
+    it; :meth:`step` is the host-in/host-out form of the same call.
 
     All backends share the single generic op sequence in ``qstep.py``.
     """
@@ -113,23 +117,17 @@ class Q15StreamStep:
         # this to a mutable dict, the exact backend's gathered step tallies
         # LUT-saturation / pre-range events into it from intermediates it
         # materializes anyway (zero extra FP work, byte-identical output).
-        # The jit/pallas dispatches are never touched — monitored runs on
-        # those backends call :meth:`tally_numeric_events` instead.
+        # The resident jit/pallas dispatches are never tallied.
         self.numeric_events = None
-        self._resident_step = None
-        if backend == "exact":
-            self._step = self._step_exact
-        elif backend == "jit":
+        if backend == "jit":
             self._jnp_arrs = self.sw.arrays(jnp)
             if self.device is not None:
                 self._jnp_arrs = {k: jax.device_put(v, self.device)
                                   for k, v in self._jnp_arrs.items()}
             self._resident_step = self._build_jit_resident()
-            self._step = self._build_jit()
-        else:
+        elif backend == "pallas":
             self._pallas_step = make_fastgrnn_step(self.sw,
                                                    device=self.device)
-            self._step = self._step_pallas
             self._resident_step = self._build_pallas_resident()
         # device-side reset: jitted masked zero (no host h round-trip)
         self._reset_resident = jax.jit(
@@ -172,21 +170,10 @@ class Q15StreamStep:
     # Every boundary crossing is booked in ``self.transfers``.
 
     @property
-    def supports_device_state(self) -> bool:
+    def resident(self) -> bool:
+        """Whether h lives on the device between ticks: true for every
+        device backend, false for the host-NumPy ``exact``."""
         return self.backend != "exact"
-
-    @property
-    def device_state_profitable(self) -> bool:
-        """Default-on policy for device residency (config ``"auto"``):
-        the backend must support it AND the topology must offer real
-        device parallelism.  On a single host-platform CPU "device" the
-        resident path buys no concurrency (same cores either way) while
-        paying the async-dispatch sync and bookkeeping — measured ~16%
-        of a fused 1024-slot tick — so "auto" keeps the bit-identical
-        host-staged path there and goes resident only on a real
-        accelerator or a multi-device topology."""
-        return self.supports_device_state and (
-            jax.default_backend() != "cpu" or len(jax.devices()) > 1)
 
     def init_state_device(self, n_slots: int):
         """Zero-initialized (S, H) resident state (created on device — no
@@ -265,8 +252,8 @@ class Q15StreamStep:
         # Deliberately NOT donate_argnums=0: buffer donation makes XLA's
         # CPU executable ~3x slower for this kernel (measured 0.20 ms vs
         # 0.066 ms per 1024-row step) AND changes its fusion by ~1 ulp,
-        # so donating would cost both throughput and the host-vs-device
-        # bit-identity contract.  The resident path doesn't need it for
+        # so donating would cost both throughput and bitwise
+        # reproducibility.  The resident path doesn't need it for
         # zero-copy — h stays on device either way; donation would only
         # save the output allocation.
         arrs, sw = self._jnp_arrs, self.sw
@@ -285,9 +272,9 @@ class Q15StreamStep:
         # which breaks the fleet's shard-count-invariant bit-identity.
         # Eager pads materialize the exact padded operands and the direct
         # pallas_call is batch-shape-stable, so this path is bitwise equal
-        # to the host-staged ``_step_pallas`` at every batch size.  The
-        # ops still dispatch asynchronously; the trade is losing h-buffer
-        # donation (the pallas output allocates regardless).
+        # across batch sizes (and to ``exact`` on a TPU).  The ops still
+        # dispatch asynchronously; the trade is losing h-buffer donation
+        # (the pallas output allocates regardless).
         pstep = self._pallas_step
         H, d = self.sw.hidden_dim, self.sw.input_dim
 
@@ -332,34 +319,30 @@ class Q15StreamStep:
         as a NumPy array.  Slots with ``active=False`` keep their hidden
         state bit-for-bit.  Logits are NOT computed here — the engine only
         needs them at emission time; call :meth:`head_logits` on the
-        emitting rows."""
-        return self._step(np.asarray(h, np.float32),
-                          np.asarray(x, np.float32),
-                          np.asarray(active, bool))
-
-    def _step_exact(self, h, x, active):
+        emitting rows.  A device backend runs :meth:`step_resident` with h
+        uploaded and downloaded around it (both booked as h-state)."""
+        h = np.asarray(h, np.float32)
+        x = np.asarray(x, np.float32)
+        active = np.asarray(active, bool)
+        if self.resident:
+            return self.to_host(self.step_resident(self.to_device(h), x,
+                                                   active))
         h_new = qstep.step_batched(np, self._np_arrs, self.sw, h, x)
         return np.where(active[:, None], h_new, h).astype(np.float32)
 
     # -- scheduler/program adapter ------------------------------------------
     def step_rows(self, h, x, active, rows=None):
         """Slot-program adapter for ``serve/scheduler.SlotScheduler``
-        consumers: advance exactly the slots listed in ``rows`` (the
-        precomputed ``np.nonzero(active)[0]``; derived here if omitted).
+        consumers on the exact backend (a resident backend steps with
+        :meth:`step_resident`): advance exactly the slots listed in
+        ``rows`` (the precomputed ``np.nonzero(active)[0]``; derived here
+        if omitted).
 
-        The exact backend computes *only* those rows — ``step_batched`` is
-        row-independent (one fixed-order f32 matvec chain per row), so the
-        gathered computation is bit-identical to the masked full-batch step
-        while skipping idle slots entirely (partial-occupancy ticks no
-        longer pay for the whole slot table).  The jit/pallas backends keep
-        the fixed-shape masked step: a varying row count would retrace /
-        repad every tick, costing more than the skipped rows save."""
-        if self.backend != "exact":
-            # the masked full-batch step never needs the row list — skip
-            # the nonzero scan entirely (it is measurable at 100k+ slots)
-            return self._step(np.asarray(h, np.float32),
-                              np.asarray(x, np.float32),
-                              np.asarray(active, bool))
+        Only those rows are computed — ``step_batched`` is row-independent
+        (one fixed-order f32 matvec chain per row), so the gathered
+        computation is bit-identical to the masked full-batch step while
+        skipping idle slots entirely (partial-occupancy ticks do not pay
+        for the whole slot table)."""
         if rows is None:
             rows = np.nonzero(active)[0]
         if rows.size == 0:
@@ -371,13 +354,13 @@ class Q15StreamStep:
         return h
 
     def tally_numeric_events(self, h, x, rows) -> None:
-        """Numeric-health tallies for the jit/pallas backends: recompute
-        the advanced rows' step on the host NumPy path purely to observe
-        its intermediates (``repro.obs.numerics``), discarding the result.
-        The accelerated dispatch itself is never modified, so monitored
-        and unmonitored runs stay byte-identical by construction; the
-        recompute cost is the price of watching an opaque executable and
-        is why monitoring defaults off.  Exact-backend callers never need
+        """Numeric-health tallies for a fused exact fleet tick, whose group
+        kernel stepped a cross-shard batch: recompute one shard's advanced
+        rows on the host purely to observe their intermediates
+        (``repro.obs.numerics``), discarding the result.  The step itself
+        is never modified, so monitored and unmonitored runs stay
+        byte-identical by construction; the recompute cost is why
+        monitoring defaults off.  A standalone exact engine never needs
         this — ``step_rows`` tallies inline for free."""
         if self.numeric_events is None or rows is None or len(rows) == 0:
             return
@@ -385,51 +368,3 @@ class Q15StreamStep:
                            np.asarray(h, np.float32)[np.asarray(rows)],
                            np.asarray(x, np.float32)[np.asarray(rows)],
                            events=self.numeric_events)
-
-    def _build_jit(self):
-        # the SAME executable as the resident path — any compilation
-        # difference (donation, extra wrapping) changes XLA's fusion
-        # choices by ~1 ulp and would break host-vs-device bit-identity.
-        # This host-staged path round-trips the full h table every tick
-        # — booked so stats()/fleet_bench can show the contrast with the
-        # zero-h-copy resident step.
-        dev, ledger, f = self.device, self.transfers, self._resident_step
-
-        def run(h, x, active):
-            ledger.h2d(x.nbytes)
-            ledger.h2d(active.nbytes)
-            ledger.h2d(h.nbytes, state=True)
-            if dev is not None:
-                h, x, active = (jax.device_put(h, dev),
-                                jax.device_put(x, dev),
-                                jax.device_put(active, dev))
-            out = np.asarray(f(h, x, active))
-            ledger.d2h(out.nbytes, state=True)
-            return out
-
-        return run
-
-    def _step_pallas(self, h, x, active):
-        S, H = h.shape
-        sp = -S % B_TILE
-        h_p = np.zeros((S + sp, LANES), np.float32)
-        h_p[:S, :H] = h
-        x_p = np.zeros((S + sp, LANES), np.float32)
-        x_p[:S, :x.shape[1]] = x
-        m_p = np.zeros((S + sp, 1), np.int32)
-        m_p[:S, 0] = active
-        # host-staged path: full padded h round-trip per tick (cf. the
-        # zero-h-copy device-resident step_resident)
-        self.transfers.h2d(x_p.nbytes)
-        self.transfers.h2d(m_p.nbytes)
-        self.transfers.h2d(h_p.nbytes, state=True)
-        if self.device is not None:
-            args = (jax.device_put(x_p, self.device),
-                    jax.device_put(h_p, self.device),
-                    jax.device_put(m_p, self.device))
-        else:
-            args = (jnp.asarray(x_p), jnp.asarray(h_p), jnp.asarray(m_p))
-        h_new = self._pallas_step(*args)
-        out = np.asarray(h_new)[:S, :H]
-        self.transfers.d2h(out.nbytes, state=True)
-        return out

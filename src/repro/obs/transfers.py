@@ -16,8 +16,8 @@ device costs a dispatch and each pull a sync, whatever its size.
 * a steady-state fused tick on the device-resident jit/pallas path books
   **zero** ``h_h2d_bytes``/``h_d2h_bytes`` (the regression gate in
   ``tests/test_device_fleet.py``);
-* the legacy host-staged path books a full ``h`` round-trip per tick —
-  the contrast ``benchmarks/fleet_bench.py`` publishes per results row.
+* a device backend's host-in/host-out ``Q15StreamStep.step`` books a
+  full ``h`` round-trip per call.
 
 Byte counts are *logical* transfer volume (what would cross PCIe/ICI on
 a real accelerator); on CPU jax may alias instead of copying, but the

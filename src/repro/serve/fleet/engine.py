@@ -82,7 +82,6 @@ class FleetConfig:
     max_pending_per_shard: int | None = None  # None = shard FIFOs unbounded
     # (nothing ever reaches the fleet spillover queue)
     placement: str = "auto"      # "auto" | "devices" | "host"
-    fuse_ticks: bool = True      # one kernel dispatch per device group/tick
     snapshot_every: int | None = None   # crash-failover checkpoint cadence
     # in fleet ticks (None = failover disabled: no snapshots, no sample
     # journal, ``crash_shard`` refuses).  Every ``snapshot_every`` ticks
@@ -183,9 +182,10 @@ class FleetEngine:
             for dev, _ in groups}
         self._devices = devices
         # device-resident fused ticks: h lives on device between ticks
-        # and the fused step is an ASYNC dispatch (all shards' config is
-        # the template, so the resolved residency is uniform)
-        self._device_resident = self.shards[0]._device_resident
+        # and the fused step is an ASYNC dispatch (every group kernel has
+        # the template's backend, so residency is uniform)
+        self._device_resident = next(
+            iter(self._group_kernels.values())).resident
         self._owner: dict[str, int] = {}   # stream -> shard (incl. pending)
         self._spilled: "collections.OrderedDict[str, _SpillEntry]" = \
             collections.OrderedDict()      # fleet-level FIFO spillover
@@ -236,8 +236,7 @@ class FleetEngine:
             self._group_list.append(g)
             for j, i in enumerate(idxs):
                 self._group_of[i] = g
-                if config.fuse_ticks:
-                    self.shards[i]._x = g.x_big[offs[j]:offs[j + 1]]
+                self.shards[i]._x = g.x_big[offs[j]:offs[j + 1]]
         # device-resident fused outputs issued this tick and not yet
         # waited on: the next tick syncs them (fleet.device_wait) BEFORE
         # phase 1 overwrites the x/mask staging the dispatch aliased
@@ -502,18 +501,7 @@ class FleetEngine:
         live = self.n_active + self.n_pending
         if len(self._owner) > 2 * live + 1024:
             self._compact_owners()       # bound stale finished-id entries
-        if not self.config.fuse_ticks:
-            self._fire("mid_dispatch")
-            events: list[StreamEvent] = []
-            rec = self.obs.recorder
-            for i, shard in enumerate(self.shards):
-                out = shard.step()
-                self._advanced_per_shard[i] = shard._last_advanced
-                if rec is not None and out:
-                    self._note_shard_events(i, out)
-                events.extend(out)
-        else:
-            events = self._step_fused()
+        events = self._step_fused()
         t0 = tr.open("fleet.deliver")
         self._deliver(events)
         tr.close(t0)
@@ -664,8 +652,8 @@ class FleetEngine:
         group that still holds its view of the group's fused output plans
         its emission on the host, and :meth:`_emit_groups` then pulls and
         resets the whole group's rows at once.  Any other shard (the
-        host-staged path, or a shard whose h was rebound since the
-        dispatch) finishes on its own."""
+        exact path, or a shard whose h was rebound since the dispatch)
+        finishes on its own."""
         tr = self._tracer
         reports: dict[int, TickReport] = {}
         plans: dict[int, Any] = {}
@@ -909,9 +897,8 @@ class FleetEngine:
         new = self._make_shard(old.config.device, shard)
         self.shards[shard] = new
         g = self._group_of[shard]
-        if self.config.fuse_ticks:    # rewire the fused-x view segment
-            j = g.idxs.index(shard)
-            new._x = g.x_big[g.offsets[j]:g.offsets[j + 1]]
+        j = g.idxs.index(shard)       # rewire the fused-x view segment
+        new._x = g.x_big[g.offsets[j]:g.offsets[j + 1]]
         g.h_big = None                # fused-h adoption restarts from concat
         g.h_views = [None] * len(g.idxs)
         replayed = 0
@@ -1057,7 +1044,7 @@ class FleetEngine:
         its own kernel and its device group's fused kernel."""
         out = []
         for i, sh in enumerate(self.shards):
-            h = sh._resolve_h() if sh._device_resident else None
+            h = sh._resolve_h() if self._device_resident else None
             consts = (sh.kernel.device_constants()
                       + self._group_of[i].kernel.device_constants())
             out.append({"device": self._devices[i],
@@ -1098,7 +1085,6 @@ class FleetEngine:
             "placement": self.config.placement,
             "devices": [str(d) if d is not None else "host"
                         for d in self._devices],
-            "fuse_ticks": self.config.fuse_ticks,
             "device_resident": self._device_resident,
             "transfers": self._transfer_totals(),
             "max_streams": slots,

@@ -28,9 +28,10 @@ Determinism contract: with the default ``backend="exact"`` every stream's
 hidden trajectory, logits and predictions are **bit-identical** to running
 the scalar ``core/qruntime.QRuntime`` over the same samples (paper
 contribution (i) — cross-platform agreement — preserved at batch scale).
-The ``"jit"`` / ``"pallas"`` backends trade that for throughput (XLA
+The ``"jit"`` / ``"pallas"`` backends keep the hidden-state table on the
+device between ticks; jit trades bit-identity for throughput (XLA
 contracts mul+add into FMA, ~1e-9/step drift; argmax predictions agree in
-practice).
+practice), and pallas is bitwise equal to exact on a TPU.
 
 Lifecycle::
 
@@ -75,15 +76,11 @@ class StreamingConfig:
     backend: str = "exact"       # "exact" | "jit" | "pallas"
     device: Any = None           # jax device for jit/pallas dispatch (fleet
     # shard placement); None = default device / process-local NumPy
-    device_resident: Any = "auto"   # keep the hidden-state table on device
-    # between ticks: steady-state ticks then move ZERO h bytes across the
-    # host/device boundary (only x + active-mask stage h2d; emission/tap/
-    # snapshot rows pull d2h on demand).  "auto" = yes for jit/pallas
-    # when the topology has real device parallelism (an accelerator or
-    # >1 device — see Q15StreamStep.device_state_profitable), never for
-    # exact — the bit-exact backend stays host NumPy.  True forces
-    # residency on any jit/pallas topology (the tests do, to pin the
-    # zero-copy contract on CPU); False forces the host-staged path.
+    device_resident: Any = "auto"   # selects nothing: where h lives
+    # follows the backend (``Q15StreamStep.resident``: jit/pallas keep it
+    # on the device between ticks, exact on the host).  Kept only because
+    # the benchmark's fleet system (bench/systems/fleet_q15.py) passes it;
+    # "auto" or the bool that agrees with the backend, anything else raises
     batch_events: bool = False   # emit one columnar StreamEventBatch per
     # tick instead of per-stream StreamEvent objects (the fleet-scale path)
     ring_capacity: int = 256     # initial per-slot sample ring (grows 2x)
@@ -241,13 +238,12 @@ class StreamingEngine:
                                     naive_acts=naive_acts,
                                     backend=config.backend,
                                     device=config.device)
-        if config.device_resident == "auto":
-            self._device_resident = self.kernel.device_state_profitable
-        else:
-            self._device_resident = bool(config.device_resident)
-            if self._device_resident and not self.kernel.supports_device_state:
-                raise ValueError("device_resident=True requires the jit or "
-                                 "pallas backend (exact is host NumPy)")
+        self._device_resident = self.kernel.resident
+        if config.device_resident not in ("auto", self._device_resident):
+            raise ValueError(
+                f"device_resident={config.device_resident!r} contradicts "
+                f"backend {config.backend!r}, whose h lives on the "
+                f"{'device' if self._device_resident else 'host'}")
         S, d = config.max_slots, self.kernel.input_dim
         self._input_dim = d
         self._h = (self.kernel.init_state_device(S) if self._device_resident
@@ -577,8 +573,6 @@ class StreamingEngine:
                     self._resolve_h(), np.array([slot]), h0[None])
                 self._h_pending = None
             else:
-                if not self._h.flags.writeable:   # jit/pallas outputs are
-                    self._h = self._h.copy()      # read-only numpy views
                 self._h[slot] = h0
             self._steps[slot] = steps0
             self._wstep[slot] = wstep0
@@ -644,12 +638,6 @@ class StreamingEngine:
         else:
             if mon is not None:
                 self.kernel.numeric_events = self._num_events
-                if self.config.backend != "exact":
-                    # jit/pallas: the accelerated dispatch is never
-                    # touched (byte-identity by construction) — recompute
-                    # the advanced rows on the host NumPy path to observe
-                    # their intermediates
-                    self.kernel.tally_numeric_events(self._h, self._x, rows)
             h_new = self.kernel.step_rows(self._h, self._x, avail, rows)
             if mon is not None:
                 self.kernel.numeric_events = None

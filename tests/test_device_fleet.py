@@ -142,9 +142,9 @@ def test_zero_h_copies_steady_state(qp, backend):
     assert after["h2d_bytes"] > before["h2d_bytes"]   # x + mask staging
 
 
-def test_host_staged_path_pays_h_roundtrip(qp):
-    """Contrast fixture for the counter semantics: the non-resident
-    (host-staged) step books the full h table both ways every tick."""
+def test_host_step_books_h_roundtrip(qp):
+    """Contrast fixture for the counter semantics: the host-in/host-out
+    ``step`` of a device backend books the full h table both ways."""
     k = Q15StreamStep(qp, backend="jit")
     h = k.init_state(8)
     x = np.zeros((8, D), np.float32)
@@ -229,28 +229,17 @@ def test_host_placement_single_group_dispatch(qp, streams):
 
 
 # ---------------------------------------------------------------------------
-# Standalone engine: device-resident vs host state is invisible
+# Standalone engine: residency follows the backend
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("backend", ["jit", "pallas"])
-def test_engine_device_vs_host_bit_identical(qp, backend):
-    streams = make_streams(6, 50, D, seed=5)
-    logs = []
-    for resident in (False, True):
-        eng = StreamingEngine(qp, StreamingConfig(
-            max_slots=6, window=8, backend=backend,
-            device_resident=resident))
-        for sid, w in streams.items():
-            eng.attach(sid, w, total_steps=len(w))
-        logs.append(collect_log(eng.drain()))
-    assert_logs_identical(logs[1], logs[0])
-
-
-def test_exact_backend_rejects_device_resident(qp):
-    with pytest.raises(ValueError, match="device_resident"):
+@pytest.mark.parametrize("backend,resident", [
+    ("exact", True), ("jit", False), ("pallas", False)])
+def test_residency_that_contradicts_the_backend_is_rejected(qp, backend,
+                                                            resident):
+    with pytest.raises(ValueError, match=f"device_resident.*'{backend}'"):
         StreamingEngine(qp, StreamingConfig(
-            max_slots=4, backend="exact", device_resident=True))
-    # auto on exact resolves to host state, silently
+            max_slots=4, backend=backend, device_resident=resident))
+    # left unset, residency follows the backend: exact keeps host state
     eng = StreamingEngine(qp, StreamingConfig(max_slots=4, backend="exact"))
     assert eng.stats()["device_resident"] is False
 
@@ -284,8 +273,8 @@ def test_migration_export_import_device_resident(qp):
 @pytest.mark.parametrize("S", [8, 13])
 def test_pallas_step_matches_exact(low_rank, S):
     """The lane-layout Pallas step against the exact backend: allclose on
-    advanced rows, bit-for-bit on held rows, and the resident path equal
-    to the host-staged one bitwise (S=13 exercises the row padding)."""
+    advanced rows, bit-for-bit on held rows (S=13 exercises the row
+    padding)."""
     cfg = fg.FastGRNNConfig(rank_w=2 if low_rank else None,
                             rank_u=8 if low_rank else None)
     qp_ = quantize_params(fg.init_params(cfg, jax.random.PRNGKey(0)),
@@ -299,8 +288,6 @@ def test_pallas_step_matches_exact(low_rank, S):
     got = pallas.step(h, x, a)
     np.testing.assert_allclose(got, exact.step(h, x, a), atol=1e-6)
     assert np.array_equal(got[~a].view(np.int32), h[~a].view(np.int32))
-    res = np.asarray(pallas.step_resident(pallas.to_device(h), x, a))
-    assert np.array_equal(res.view(np.int32), got.view(np.int32))
 
 
 def test_roofline_report(qp):
@@ -399,7 +386,7 @@ def _emission_run(qp, streams, *, backend, placement, shards, case,
     fleet = FleetEngine(qp, FleetConfig(
         shards=shards, placement=placement,
         stream=StreamingConfig(max_slots=len(streams), window=8,
-                               backend=backend, device_resident=True),
+                               backend=backend),
         snapshot_every=5), faults=faults)
     if case == "rebound_shard":
         # rebind one shard's h after every other dispatch (as an

@@ -453,7 +453,7 @@ def _resident_fleet(qp, obs=None):
     """Two shards on one device, resident h, 8-sample windows."""
     return FleetEngine(qp, FleetConfig(
         shards=2, placement="host", stream=StreamingConfig(
-            max_slots=4, window=8, backend="jit", device_resident=True)),
+            max_slots=4, window=8, backend="jit")),
         obs=obs)
 
 
